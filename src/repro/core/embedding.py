@@ -49,7 +49,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.compat import shard_map
 from repro.core.partitioning import PartitionPlan
 
 Array = jax.Array
@@ -89,6 +88,15 @@ def _dispatch(path: str, *, vocab: int, dim: int, batch: int, bag_len,
 
 def _default_interpret(interpret: bool | None) -> bool:
     return jax.default_backend() != "tpu" if interpret is None else interpret
+
+
+def _check_vma(backend: str, interpret: bool) -> bool:
+    """``check_vma`` for a ``jax.shard_map`` whose body runs stage 2 on
+    ``backend``. JAX's Pallas HLO interpreter evaluates a kernel body
+    without varying-axis types, which the check rejects, so it is off only
+    around an interpreted kernel: the compiled kernel and the jnp path keep
+    it on."""
+    return not (backend == "pallas" and interpret)
 
 
 def _resolve_bwd(bwd_backend: str, fwd_backend: str) -> str:
@@ -235,6 +243,20 @@ def pack_replicated(table: np.ndarray, rplan, *,
 # stage 2, jnp backend: segment-scan over the bag length
 # ---------------------------------------------------------------------------
 
+def _carry_like(init: Array, *refs) -> Array:
+    """A scan carry's initial value, varying over every manual mesh axis
+    the ``refs`` vary over. Inside ``shard_map`` a carry must enter with
+    the type the body returns, and a body that reads bank-local rows or
+    batch-sharded ids returns a varying value. Outside, a no-op."""
+    axes = set()
+    for r in refs:
+        if r is not None:
+            axes |= set(jax.typeof(r).vma)
+    if not axes:
+        return init
+    return jax.lax.pcast(init, tuple(sorted(axes)), to="varying")
+
+
 def _field_offsets_per_bag(off: Array, n: int) -> Array:
     """Bag n of a flattened (..., F, L) batch belongs to field n % F."""
     return off[jnp.arange(n, dtype=jnp.int32) % off.shape[0]]
@@ -268,8 +290,9 @@ def _bag_partial_scan(table: Array, idx: Array, *, remap: Array | None,
         rows = jnp.take(table, jnp.where(mine, src, 0), axis=0)
         return acc + jnp.where(mine[:, None], rows, 0).astype(acc.dtype), None
 
-    acc, _ = jax.lax.scan(body, jnp.zeros((N, dim), jnp.float32),
-                          jnp.arange(L))
+    init = _carry_like(jnp.zeros((N, dim), jnp.float32), table, idx, remap,
+                       bank, my_bank, off)
+    acc, _ = jax.lax.scan(body, init, jnp.arange(L))
     return acc.reshape(*lead, dim).astype(table.dtype)
 
 
@@ -316,11 +339,10 @@ def _pallas_bag(cfg: tuple, packed: Array, bank: Array, slot: Array,
     tile_b, interpret, _, n_slots = cfg
     lead, L = idx.shape[:-1], idx.shape[-1]
     flat, n = _pad_bags(idx.reshape(-1, L).astype(jnp.int32), tile_b)
-    table, d = _pad_lanes(packed, interpret)
     out = banked_embedding_bag_pallas(
-        table, bank, slot, off, my.reshape(1).astype(jnp.int32), flat,
+        packed, bank, slot, off, my.reshape(1).astype(jnp.int32), flat,
         tile_b=tile_b, interpret=interpret, n_slots=n_slots)
-    return out[:n, :d].reshape(*lead, d)
+    return out[:n].reshape(*lead, packed.shape[-1])
 
 
 def _pallas_bag_fwd(cfg, packed, bank, slot, off, my, idx):
@@ -383,8 +405,9 @@ def _replicated_bag_scan(table: Array, idx: Array, *, bank_flat: Array,
         rows = jnp.take(table, src, axis=0)
         return acc + jnp.where(mine[:, None], rows, 0).astype(acc.dtype), None
 
-    acc, _ = jax.lax.scan(body, jnp.zeros((N, dim), jnp.float32),
-                          jnp.arange(L))
+    init = _carry_like(jnp.zeros((N, dim), jnp.float32), table, idx,
+                       bank_flat, slot_flat, my_bank, off)
+    acc, _ = jax.lax.scan(body, init, jnp.arange(L))
     return acc.reshape(*lead, dim).astype(table.dtype)
 
 
@@ -410,8 +433,9 @@ def _replicated_scatter_ct(shape, dtype, bank_flat, slot_flat, my, idx, ct,
         upd = jnp.where(mine[:, None], ctf, 0)
         return d_tab.at[src].add(upd), None
 
-    d_tab, _ = jax.lax.scan(body, jnp.zeros(shape, jnp.float32),
-                            jnp.arange(L))
+    init = _carry_like(jnp.zeros(shape, jnp.float32), bank_flat, slot_flat,
+                       my, idx, ct, off)
+    d_tab, _ = jax.lax.scan(body, init, jnp.arange(L))
     return d_tab.astype(dtype)
 
 
@@ -431,13 +455,12 @@ def _replicated_bag(cfg: tuple, packed: Array, bank_flat: Array,
         from repro.kernels.embedding_bag import banked_embedding_bag_pallas
         lead, L = idx.shape[:-1], idx.shape[-1]
         flat, n = _pad_bags(idx.reshape(-1, L).astype(jnp.int32), tile_b)
-        table, d = _pad_lanes(packed, interpret)
         out = banked_embedding_bag_pallas(
-            table, bank_flat, slot_flat, off,
+            packed, bank_flat, slot_flat, off,
             my.reshape(1).astype(jnp.int32), flat,
             tile_b=tile_b, interpret=interpret, k_max=k_max,
             n_slots=n_slots)
-        return out[:n, :d].reshape(*lead, d)
+        return out[:n].reshape(*lead, packed.shape[-1])
     return _replicated_bag_scan(packed, idx, bank_flat=bank_flat,
                                 slot_flat=slot_flat, my_bank=my, off=off,
                                 k_max=k_max)
@@ -498,8 +521,9 @@ def _tiered_partial_scan(payload: Array, scale: Array, tier: Array,
                                 dim, hot_dtype)
         return acc + jnp.where(mine[:, None], rows, 0.0), None
 
-    acc, _ = jax.lax.scan(body, jnp.zeros((N, dim), jnp.float32),
-                          jnp.arange(L))
+    init = _carry_like(jnp.zeros((N, dim), jnp.float32), payload, scale,
+                       tier, idx, remap, bank, my_bank, off)
+    acc, _ = jax.lax.scan(body, init, jnp.arange(L))
     return acc.reshape(*lead, dim)
 
 
@@ -618,8 +642,9 @@ def _scatter_bag_ct(shape, dtype, bank, slot, my, idx, ct, *, off=None):
         upd = jnp.where(mine[:, None], ctf, 0)
         return d_tab.at[src].add(upd), None
 
-    d_tab, _ = jax.lax.scan(body, jnp.zeros(shape, jnp.float32),
-                            jnp.arange(L))
+    init = _carry_like(jnp.zeros(shape, jnp.float32), bank, slot, my, idx,
+                       ct, off)
+    d_tab, _ = jax.lax.scan(body, init, jnp.arange(L))
     return d_tab.astype(dtype)
 
 
@@ -891,8 +916,8 @@ def banked_embedding_bag(t: BankedTable, idx: Array, dist: DistCtx | None,
 
     bank_map = t.remap_bank if bank_live is None \
         else _effective_bank_map(t.remap_bank, bank_live, t.n_banks)
-    return shard_map(
-        fn, mesh=dist.mesh,
+    return jax.shard_map(
+        fn, mesh=dist.mesh, check_vma=_check_vma(backend, interpret),
         in_specs=(P(bank, None), P(), P(), P(), idx_spec),
         out_specs=out_spec,
     )(t.packed, bank_map, t.remap_slot, off, idx)
@@ -1073,8 +1098,8 @@ def tiered_embedding_bag(fp_packed: Array, tt, idx: Array,
                            my.astype(jnp.int32), idx_local)
         return jax.lax.psum(part, bank_ax)
 
-    return shard_map(
-        fn, mesh=dist.mesh,
+    return jax.shard_map(
+        fn, mesh=dist.mesh, check_vma=_check_vma(backend, interpret),
         in_specs=(P(bank_ax, None), P(bank_ax, None), P(bank_ax),
                   P(bank_ax), P(), P(), P(), idx_spec),
         out_specs=out_spec,
@@ -1181,8 +1206,8 @@ def banked_cache_residual_bag(t: BankedTable, cache: BankedTable,
     else:
         e_map = _effective_bank_map(t.remap_bank, bank_live, t.n_banks)
         c_map = _effective_bank_map(cache.remap_bank, bank_live, cache.n_banks)
-    return shard_map(
-        fn, mesh=dist.mesh,
+    return jax.shard_map(
+        fn, mesh=dist.mesh, check_vma=_check_vma(backend, interpret),
         in_specs=(P(bank, None), P(bank, None), P(), P(), P(), P(),
                   ci_spec, ri_spec),
         out_specs=out_spec,
@@ -1305,8 +1330,8 @@ def csr_embedding_bag(t: BankedTable, indices: Array, offsets: Array,
             part = jax.ops.segment_sum(part, seg_local, num_bags)
         return jax.lax.psum(part, dist.bank_axis)
 
-    return shard_map(
-        fn, mesh=dist.mesh,
+    return jax.shard_map(
+        fn, mesh=dist.mesh, check_vma=_check_vma(backend, interpret),
         in_specs=(P(dist.bank_axis, None), P(), P(), P(), P(), P()),
         out_specs=P(),
     )(t.packed, t.remap_bank, t.remap_slot, indices, seg, offs_ext)
@@ -1434,8 +1459,8 @@ def csr_embedding_bag_sharded(t: BankedTable, indices: np.ndarray,
             part = jax.ops.segment_sum(part, seg_local, num_bags)
         return jax.lax.psum(part, (*dist.dp_axes, bank))
 
-    return shard_map(
-        fn, mesh=dist.mesh,
+    return jax.shard_map(
+        fn, mesh=dist.mesh, check_vma=_check_vma(backend, interpret),
         in_specs=(P(bank, None), P(), P(), P(dp, None), P(dp, None),
                   P(dp, None)),
         out_specs=P(),

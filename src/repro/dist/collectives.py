@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.compat import shard_map
 from repro.core.embedding import DistCtx
 
 Array = jax.Array
@@ -115,7 +114,7 @@ def seqsharded_decode_attention(q: Array, k_new: Array, v_new: Array,
         out = o_g / jnp.maximum(l_g, 1e-30)[..., None]
         return out.reshape(b, Hq, Dh).astype(q.dtype), kc, vc
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(bspec, None, None), P(bspec, None, None),
                   P(bspec, None, None),

@@ -1,27 +1,30 @@
 """Pallas TPU kernels: embedding-bag gather+reduce "near memory".
 
 TPU adaptation of the paper's in-DPU lookup (DESIGN.md §5, paper §3.1/Fig. 7).
-The table(s) stay in HBM (`pltpu.ANY`); bag indices and the row->(bank, slot)
-remap vectors are scalar-prefetched (SMEM) so the kernel can compute HBM row
-addresses *before* touching vector memory; rows stream HBM->VMEM through an
-N-slot rotation of `pltpu.make_async_copy` DMAs (`n_slots`, default 2 =
+The table(s) stay in HBM (`pl.ANY`); the HBM row of every entry is known
+from SMEM before vector memory is touched (the banked forward gets each
+tile's resolved rows as an SMEM block, the other kernels scalar-prefetch
+their indices and row->(bank, slot) remap vectors); rows stream HBM->VMEM
+through an N-slot rotation of `pltpu.make_async_copy` DMAs (`n_slots`, default 2 =
 classic ping-pong: up to N-1 copies are in flight while entry e is being
 accumulated — the pipeline depth the autotuner sweeps). Each grid step owns a tile of
 bags and writes only the reduced (tile_b, D) block — the (B*L, D) gathered
 matrix a naive XLA gather would materialize never exists.
 
-What runs inside the kernel (vs. the seed's wrapper-side precompute):
+Entry resolution:
   * per-field row offsets      — bag b belongs to field b % n_fields; its raw
     ids are shifted by `field_offsets[f]`, so ALL F sparse fields of a DLRM
     batch are one kernel invocation over (B*F, L) bags
-  * bank/slot remap + ownership mask — the PIM stage-2 test `bank[row] == my`
-    happens on the prefetched scalars; foreign rows cost no DMA bandwidth to
-    accumulate (they are masked), and the wrapper no longer materializes a
-    masked index tensor per bank
+  * bank/slot remap + ownership mask — the PIM stage-2 test `bank[row] == my`;
+    foreign rows cost no DMA bandwidth to accumulate (they are masked)
   * fused cache + residual     — one accumulator walks the cache-entry stream
     then the residual stream (Fig. 7's `Σ cache_partials + Σ residual_rows`)
 
-Ownership is disabled by passing ``my_bank < 0`` (the unsharded path).
+The banked forward resolves offsets, remap and ownership with XLA gathers
+before the kernel (``resolve_entries``), so its SMEM is bounded by the tile;
+the tiered, fused-cache and CSR kernels still resolve in-kernel from
+scalar-prefetched remaps. Ownership is disabled by passing ``my_bank < 0``
+(the unsharded path).
 
 The TRAINING BACKWARD lives here too: ``ct_scatter_bag_pallas`` /
 ``ct_scatter_csr_pallas`` scatter-add the bag cotangents back onto the bank's
@@ -29,9 +32,11 @@ rows with the same double-buffered row DMA (cotangents in, accumulated rows
 out) — slot collisions are resolved by a slot-sorted permutation computed in
 the traced prep, never by atomics (see the backward section below).
 
-Alignment: D is padded to the 128-lane boundary by the wrappers (the TPU
-analogue of the paper's 8-byte MRAM alignment rule); each row copy is one
-(1, D) DMA — the ``N_c``-wide access of §3.1 with TPU constants.
+Alignment: rows sit on the 128-lane boundary (the TPU analogue of the
+paper's 8-byte MRAM alignment rule) — the plain/banked forward packs
+``128 // D`` rows per lane row (``pack_lanes``), the other wrappers pad D;
+each row copy is one (1, 128k) DMA — the ``N_c``-wide access of §3.1 with
+TPU constants.
 """
 from __future__ import annotations
 
@@ -76,6 +81,8 @@ def _dma_accumulate(acc, table_ref, buf, sem, start, end, src_fn, meta_fn,
         def _(k=k):
             dma(start + k, k).start()
 
+    acc_rows = jax.lax.broadcasted_iota(jnp.int32, (acc.shape[0], 1), 0)
+
     def body(e, acc):
         slot = (e - start) % n_slots
 
@@ -85,10 +92,12 @@ def _dma_accumulate(acc, table_ref, buf, sem, start, end, src_fn, meta_fn,
 
         dma(e, slot).wait()
         bag_local, mine = meta_fn(e)
-        raw = buf[slot][0]
+        raw = buf[slot]                                    # (1, D)
         val = raw.astype(jnp.float32) if row_fn is None else row_fn(e, raw)
         row = jnp.where(mine, val, 0.0)
-        return acc.at[bag_local].add(row)
+        # masked add over the tile's rows: Mosaic cannot lower a dynamic-row
+        # update of a loop-carried value; the other rows add +0.0 (exact)
+        return acc + jnp.where(acc_rows == bag_local, row, 0.0)
 
     return jax.lax.fori_loop(start, end, body, acc)
 
@@ -113,25 +122,46 @@ def replica_of_bag(bag: jax.Array, k_max: int) -> jax.Array:
     return (wang_hash(bag) % jnp.uint32(k_max)).astype(jnp.int32)
 
 
-def _entry_fns(idx_ref, bank_ref, slot_ref, off_ref, my, b0, bag_len,
-               n_fields, k_max: int = 1):
-    """(src_fn, meta_fn) for a rectangular (bags x bag_len) index stream with
-    in-kernel field offsets, remap, and ownership mask. ``e`` is the
-    tile-LOCAL entry id in [0, tile_b * bag_len).
+def resolve_entries(idx: jax.Array, bank: jax.Array, slot: jax.Array,
+                    field_offsets: jax.Array, my_bank: jax.Array,
+                    k_max: int = 1) -> jax.Array:
+    """(NB, L) raw per-field ids -> (NB, L) int32 local table rows, -1 where
+    the entry is padding or belongs to another bank.
+
+    The forward kernel's and the backward scatter's shared prep, as XLA
+    gathers outside the kernel: bag b belongs to field ``b % F`` and shifts
+    its ids by ``field_offsets[b % F]``; the row resolves through the
+    ``bank``/``slot`` remap, and ``my_bank < 0`` owns every row.
 
     ``k_max > 1`` is the replicated-table path: bank/slot are the FLATTENED
     ``(vocab * k_max,)`` replica-axis remap, and each bag reads copy
     ``wang_hash(bag) % k_max`` of every row it touches — replicas split a
-    hot row's traffic with no host-side routing. ``k_max == 1`` traces the
-    exact single-copy path (no hash in the graph).
+    hot row's traffic with no host-side routing.
+    """
+    NB = idx.shape[0]
+    bag = jnp.arange(NB, dtype=jnp.int32)[:, None]
+    valid = idx >= 0
+    row = jnp.where(valid, idx + field_offsets[bag % field_offsets.shape[0]],
+                    0)
+    if k_max > 1:
+        row = row * k_max + replica_of_bag(bag, k_max)
+    my = my_bank.reshape(())
+    mine = valid & ((my < 0) | (bank[row] == my))
+    return jnp.where(mine, slot[row], -1).astype(jnp.int32)
+
+
+def _entry_fns(idx_ref, bank_ref, slot_ref, off_ref, my, b0, bag_len,
+               n_fields):
+    """(src_fn, meta_fn) for a rectangular (bags x bag_len) index stream with
+    in-kernel field offsets, remap, and ownership mask (the tiered and
+    fused-cache kernels, which still scalar-prefetch their remap). ``e`` is
+    the tile-LOCAL entry id in [0, tile_b * bag_len).
     """
     def resolve(e):
         bag = b0 + e // bag_len
-        raw = idx_ref[bag * bag_len + e % bag_len]
+        raw = idx_ref[b0 * bag_len + e]
         valid = raw >= 0
         row = jnp.where(valid, raw + off_ref[bag % n_fields], 0)
-        if k_max > 1:
-            row = row * k_max + replica_of_bag(bag, k_max)
         mine = valid & ((my < 0) | (bank_ref[row] == my))
         return row, mine
 
@@ -146,11 +176,12 @@ def _entry_fns(idx_ref, bank_ref, slot_ref, off_ref, my, b0, bag_len,
     return src_fn, meta_fn
 
 
-def _plain_entry_fns(idx_ref, b0, bag_len):
+def _plain_entry_fns(load, bag_len):
     """(src_fn, meta_fn) for an identity-mapped index stream — no remap
-    vectors, no ownership test (the single-table drop-in wrappers)."""
+    vectors, no ownership test: ``load(e)`` is entry e's table row, -1
+    skips."""
     def resolve(e):
-        raw = idx_ref[(b0 + e // bag_len) * bag_len + e % bag_len]
+        raw = load(e)
         return jnp.maximum(raw, 0), raw >= 0
 
     def src_fn(e):
@@ -196,17 +227,55 @@ def pad_leading(x: jax.Array, mult: int, fill=-1) -> tuple[jax.Array, int]:
     return x, n
 
 
+def pack_lanes(table: jax.Array) -> tuple[jax.Array, int]:
+    """(V, D) -> (lane-dense table, rows per lane row ``pack``).
+
+    A 2-D table's rows are padded to 128 lanes in HBM, so a (V, 32) fp32
+    table occupies four times its bytes, and a (1, D) row DMA must be
+    128-lane wide. Packing ``pack = 128 // D'`` rows into each 128-lane row
+    (D' = D rounded up to a power of two) gives the kernel whole-lane rows
+    at the table's own size; D > 128 pads to a multiple of 128 instead
+    (``pack`` = 1). Row r lives in lane row ``r // pack`` at lane offset
+    ``(r % pack) * D'``.
+    """
+    V, D = table.shape
+    if D >= 128:
+        return pad_last_dim(table)[0], 1
+    width = 1 << (D - 1).bit_length()
+    pack = 128 // width
+    table = pad_leading(pad_last_dim(table, width)[0], pack, fill=0)[0]
+    return table.reshape(-1, 128), pack
+
+
 # ---------------------------------------------------------------------------
 # kernels
 # ---------------------------------------------------------------------------
 
 def _plain_bag_kernel(idx_ref, table_ref, out_ref, buf, sem, *,
-                      tile_b: int, bag_len: int, dim: int):
-    b0 = pl.program_id(0) * tile_b
-    src_fn, meta_fn = _plain_entry_fns(idx_ref, b0, bag_len)
-    acc = jnp.zeros((tile_b, dim), jnp.float32)
+                      tile_b: int, bag_len: int, pack: int):
+    """``idx_ref`` is this tile's (tile_b, bag_len) SMEM block of table rows
+    (-1 skips), so SMEM use scales with the tile, not the batch.
+    ``table_ref`` is the ``pack_lanes`` layout: each entry DMAs its row's
+    128-lane row, and a lane rotation brings the row to lanes [0, D'). The
+    other lanes accumulate neighbours' values, which the wrapper drops."""
+    width = table_ref.shape[1]
+
+    def entry(e):
+        return idx_ref[e // bag_len, e % bag_len]
+
+    def src_fn(e):
+        return jnp.maximum(entry(e), 0) // pack
+
+    def meta_fn(e):
+        return e // bag_len, entry(e) >= 0
+
+    def row_fn(e, raw):
+        shift = (width - (jnp.maximum(entry(e), 0) % pack) * (width // pack))
+        return pltpu.roll(raw.astype(jnp.float32), shift % width, 1)
+
+    acc = jnp.zeros((tile_b, width), jnp.float32)
     acc = _dma_accumulate(acc, table_ref, buf, sem, 0, tile_b * bag_len,
-                          src_fn, meta_fn)
+                          src_fn, meta_fn, row_fn if pack > 1 else None)
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
@@ -215,8 +284,8 @@ def _plain_fused_kernel(cache_idx_ref, resid_idx_ref, c_len_ref, r_len_ref,
                         tile_b: int, lc: int, lr: int, dim: int):
     b0 = pl.program_id(0) * tile_b
     acc = jnp.zeros((tile_b, dim), jnp.float32)
-    c_src, c_meta = _plain_entry_fns(cache_idx_ref, b0, lc)
-    r_src, r_meta = _plain_entry_fns(resid_idx_ref, b0, lr)
+    c_src, c_meta = _plain_entry_fns(lambda e: cache_idx_ref[b0 * lc + e], lc)
+    r_src, r_meta = _plain_entry_fns(lambda e: resid_idx_ref[b0 * lr + e], lr)
     # per-bag early exit on the prefetched effective lengths (CSR-style):
     # the walk stops at each bag's last valid entry instead of masked-
     # accumulating the full L — all-pad bags cost zero DMAs
@@ -225,20 +294,6 @@ def _plain_fused_kernel(cache_idx_ref, resid_idx_ref, c_len_ref, r_len_ref,
                               i * lc + c_len_ref[b0 + i], c_src, c_meta)
         acc = _dma_accumulate(acc, emt_ref, buf, sem, i * lr,
                               i * lr + r_len_ref[b0 + i], r_src, r_meta)
-    out_ref[...] = acc.astype(out_ref.dtype)
-
-
-def _banked_bag_kernel(idx_ref, bank_ref, slot_ref, off_ref, my_ref,
-                       table_ref, out_ref, buf, sem, *,
-                       tile_b: int, bag_len: int, n_fields: int, dim: int,
-                       k_max: int = 1):
-    b0 = pl.program_id(0) * tile_b
-    src_fn, meta_fn = _entry_fns(idx_ref, bank_ref, slot_ref, off_ref,
-                                 my_ref[0], b0, bag_len, n_fields,
-                                 k_max=k_max)
-    acc = jnp.zeros((tile_b, dim), jnp.float32)
-    acc = _dma_accumulate(acc, table_ref, buf, sem, 0, tile_b * bag_len,
-                          src_fn, meta_fn)
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
@@ -294,7 +349,15 @@ def _tiered_bag_kernel(idx_ref, bank_ref, slot_ref, off_ref, my_ref,
     def row_fn(e, raw):
         s = src_fn(e)
         scale = jax.lax.bitcast_convert_type(scale_ref[s], jnp.float32)
-        return dequant_rows_f32(raw, scale, tier_ref[s], dim, hot_dtype)
+        tier = tier_ref[s]
+        val = dequant_rows_f32(raw, scale, tier, dim, hot_dtype)
+        # the row is rounded to fp32 before it is accumulated, as in the jnp
+        # fallback: a xor with a zero no compiler can prove (tiers are >= 0)
+        # keeps XLA:CPU, in interpret mode, from contracting the dequant
+        # multiply and the accumulate add into one FMA
+        bits = (jax.lax.bitcast_convert_type(val, jnp.int32)
+                ^ jnp.minimum(tier, 0))
+        return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
     acc = jnp.zeros((tile_b, dim), jnp.float32)
     acc = _dma_accumulate(acc, payload_ref, buf, sem, 0, tile_b * bag_len,
@@ -454,6 +517,14 @@ def _csr_bag_kernel(idx_ref, seg_ref, offs_ref, bank_ref, slot_ref, my_ref,
 # pallas_call wrappers (shape plumbing only — padding stays in the callers)
 # ---------------------------------------------------------------------------
 
+def _out_struct(shape, dtype, operands) -> jax.ShapeDtypeStruct:
+    """A kernel's output type: varying over every manual mesh axis its
+    operands vary over, which ``jax.shard_map`` checks (no axes outside
+    one)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
 def _scratch(dim: int, dtype, n_slots: int = 2):
     """Row-DMA scratch: ``n_slots`` (1, dim) VMEM slots + matching DMA
     semaphores. ``_dma_accumulate`` reads the pipeline depth off the buffer
@@ -469,36 +540,22 @@ def banked_embedding_bag_pallas(table: jax.Array, bank: jax.Array,
                                 tile_b: int = 8, interpret: bool = False,
                                 k_max: int = 1, n_slots: int = 2
                                 ) -> jax.Array:
-    """One bank's stage-2 partial bag sums, remap + mask in-kernel.
+    """One bank's stage-2 partial bag sums.
 
-    table (R, D) local rows in HBM; bank/slot (V,) int32 remap (prefetched);
+    table (R, D) local rows in HBM; bank/slot (V,) int32 remap;
     field_offsets (F,) int32; my_bank (1,) int32 (< 0 disables the ownership
     test); idx (NB, L) int32 raw per-field ids, -1 padded. -> (NB, D).
 
-    ``k_max > 1`` serves a REPLICATED table: bank/slot are the flattened
-    ``(V * k_max,)`` replica-axis remap and each bag's reads resolve through
-    replica column ``wang_hash(bag) % k_max`` (see ``_entry_fns``); the
-    kernel body is otherwise unchanged — same prefetch streams, same DMA
-    ping-pong, one extra SMEM index multiply per entry.
+    Offsets, remap and ownership resolve outside the kernel
+    (``resolve_entries``, XLA gathers), so the kernel sees only each tile's
+    block of local rows: its SMEM holds ``tile_b * L`` entries whatever the
+    vocab or the batch. ``k_max > 1`` serves a REPLICATED table: bank/slot
+    are the flattened ``(V * k_max,)`` replica-axis remap and each bag reads
+    replica column ``wang_hash(bag) % k_max``.
     """
-    NB, L = idx.shape
-    R, D = table.shape
-    assert NB % tile_b == 0, (NB, tile_b)
-    kernel = functools.partial(
-        _banked_bag_kernel, tile_b=tile_b, bag_len=L,
-        n_fields=field_offsets.shape[0], dim=D, k_max=k_max)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(NB // tile_b,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((tile_b, D), lambda b, *_: (b, 0)),
-        scratch_shapes=_scratch(D, table.dtype, n_slots),
-    )
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((NB, D), table.dtype),
-        interpret=interpret,
-    )(idx.reshape(-1), bank, slot, field_offsets, my_bank, table)
+    rows = resolve_entries(idx, bank, slot, field_offsets, my_bank, k_max)
+    return embedding_bag_pallas(table, rows, tile_b=tile_b,
+                                interpret=interpret, n_slots=n_slots)
 
 
 def tiered_embedding_bag_pallas(payload: jax.Array, scale_bits: jax.Array,
@@ -524,43 +581,48 @@ def tiered_embedding_bag_pallas(payload: jax.Array, scale_bits: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=(NB // tile_b,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((tile_b, dim), lambda b, *_: (b, 0)),
         scratch_shapes=_scratch(payload.shape[-1], payload.dtype, n_slots),
     )
+    args = (idx.reshape(-1), bank, slot, field_offsets, my_bank, tier,
+            scale_bits, payload)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((NB, dim), jnp.float32),
+        out_shape=_out_struct((NB, dim), jnp.float32, args),
         interpret=interpret,
-    )(idx.reshape(-1), bank, slot, field_offsets, my_bank, tier, scale_bits,
-      payload)
+    )(*args)
 
 
 def embedding_bag_pallas(table: jax.Array, idx: jax.Array, *,
                          tile_b: int = 8, interpret: bool = False,
                          n_slots: int = 2) -> jax.Array:
-    """Plain bag sum: table (V, D); idx (B, L) -1 padded -> (B, D).
+    """Plain bag sum: table (V, D); idx (B, L) table rows, -1 padded ->
+    (B, D).
 
-    Remap-free variant: rows are table positions, so no (V,)-sized scalar
-    operands hit SMEM — any vocab size works on real TPUs.
+    Each grid step gets its tile's ``tile_b * L`` rows as an SMEM block, and
+    the table stays in HBM in the ``pack_lanes`` layout: SMEM use is bounded
+    by the tile, so any vocab and any batch fit, and HBM holds the table at
+    its own size.
     """
     B, L = idx.shape
-    V, D = table.shape
+    D = table.shape[1]
     assert B % tile_b == 0, (B, tile_b)
+    packed, pack = pack_lanes(table)
+    W = packed.shape[1]
     kernel = functools.partial(_plain_bag_kernel, tile_b=tile_b, bag_len=L,
-                               dim=D)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B // tile_b,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((tile_b, D), lambda b, *_: (b, 0)),
-        scratch_shapes=_scratch(D, table.dtype, n_slots),
-    )
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, D), table.dtype),
+                               pack=pack)
+    out = pl.pallas_call(
+        kernel, grid=(B // tile_b,),
+        in_specs=[pl.BlockSpec((tile_b, L), lambda b: (b, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((tile_b, W), lambda b: (b, 0)),
+        scratch_shapes=_scratch(W, table.dtype, n_slots),
+        out_shape=_out_struct((B, W), table.dtype, (idx, packed)),
         interpret=interpret,
-    )(idx.reshape(-1), table)
+    )(idx, packed)
+    return out[:, :D]
 
 
 def plain_cache_bag_pallas(emt: jax.Array, cache: jax.Array,
@@ -580,18 +642,19 @@ def plain_cache_bag_pallas(emt: jax.Array, cache: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B // tile_b,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((tile_b, D), lambda b, *_: (b, 0)),
         scratch_shapes=_scratch(D, emt.dtype, n_slots),
     )
+    args = (cache_idx.reshape(-1), residual_idx.reshape(-1),
+            effective_lengths(cache_idx), effective_lengths(residual_idx),
+            cache, emt)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, D), emt.dtype),
+        out_shape=_out_struct((B, D), emt.dtype, args),
         interpret=interpret,
-    )(cache_idx.reshape(-1), residual_idx.reshape(-1),
-      effective_lengths(cache_idx), effective_lengths(residual_idx),
-      cache, emt)
+    )(*args)
 
 
 def fused_cache_bag_pallas(emt: jax.Array, cache: jax.Array,
@@ -614,19 +677,20 @@ def fused_cache_bag_pallas(emt: jax.Array, cache: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=10,
         grid=(B // tile_b,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((tile_b, D), lambda b, *_: (b, 0)),
         scratch_shapes=_scratch(D, emt.dtype, n_slots),
     )
+    args = (cache_idx.reshape(-1), residual_idx.reshape(-1),
+            effective_lengths(cache_idx), effective_lengths(residual_idx),
+            cache_bank, cache_slot, emt_bank, emt_slot, my_bank,
+            jnp.zeros((1,), jnp.int32), cache, emt)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, D), emt.dtype),
+        out_shape=_out_struct((B, D), emt.dtype, args),
         interpret=interpret,
-    )(cache_idx.reshape(-1), residual_idx.reshape(-1),
-      effective_lengths(cache_idx), effective_lengths(residual_idx),
-      cache_bank, cache_slot, emt_bank, emt_slot, my_bank,
-      jnp.zeros((1,), jnp.int32), cache, emt)
+    )(*args)
 
 
 def _scatter_scratch(dim: int, ct_dtype, out_dtype, n_slots: int = 2):
@@ -641,16 +705,11 @@ def _scatter_scratch(dim: int, ct_dtype, out_dtype, n_slots: int = 2):
             pltpu.SemaphoreType.DMA((2,))]
 
 
-def _dest_slots(row: jax.Array, valid: jax.Array, bank: jax.Array,
-                slot: jax.Array, my_bank: jax.Array,
-                n_rows: int) -> jax.Array:
-    """The race-freedom invariant, in ONE place: an entry scatters iff it is
-    valid AND owned (``my < 0`` disables ownership), onto ``slot[row]``;
-    everything else gets the out-of-range sentinel that sorts it out of
-    every run."""
-    my = my_bank.reshape(())
-    mine = valid & ((my < 0) | (bank[row] == my))
-    return jnp.where(mine, slot[row], n_rows)
+def _dest_slots(rows: jax.Array, n_rows: int) -> jax.Array:
+    """The race-freedom invariant, in ONE place: an entry scatters iff
+    ``resolve_entries`` gave it a local row (valid AND owned); everything
+    else gets the out-of-range sentinel that sorts it out of every run."""
+    return jnp.where(rows >= 0, rows, n_rows)
 
 
 def _ct_scatter_call(ct: jax.Array, dest: jax.Array, bags: jax.Array,
@@ -668,20 +727,25 @@ def _ct_scatter_call(ct: jax.Array, dest: jax.Array, bags: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(n_tiles,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=_scatter_scratch(D, ctp.dtype, out_dtype, n_slots),
     )
+    args = (bag_sorted, run_of, run_starts, run_slot, n_run, ctp)
+    out_type = _out_struct((n_rows, D), out_dtype, args)
+    zeros = jnp.zeros((n_rows, D), out_dtype)
+    if out_type.vma:
+        zeros = jax.lax.pcast(zeros, tuple(sorted(out_type.vma)),
+                              to="varying")
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_rows, D), out_dtype),
+        out_shape=out_type,
         # d_table aliases a zeros input (operand 6 = 5 scalars + ct): only
         # touched rows are DMA'd, the rest must already BE zero
         input_output_aliases={6: 0},
         interpret=interpret,
-    )(bag_sorted, run_of, run_starts, run_slot, n_run, ctp,
-      jnp.zeros((n_rows, D), out_dtype))
+    )(*args, zeros)
     return out[:, :d]
 
 
@@ -712,17 +776,11 @@ def ct_scatter_bag_pallas(ct: jax.Array, idx: jax.Array, bank: jax.Array,
     row's copies recovers the single-copy gradient.
     """
     NB, L = idx.shape
-    E = NB * L
-    F = field_offsets.shape[0]
-    e = jnp.arange(E, dtype=jnp.int32)
-    bag, j = e % NB, e // NB
-    raw = idx.reshape(-1)[bag * L + j]
-    valid = raw >= 0
-    row = jnp.where(valid, raw + field_offsets[bag % F], 0)
-    if k_max > 1:
-        row = row * k_max + replica_of_bag(bag, k_max)
-    dest = _dest_slots(row, valid, bank, slot, my_bank, n_rows)
-    return _ct_scatter_call(ct, dest, bag, n_rows, out_dtype,
+    rows = resolve_entries(idx, bank, slot, field_offsets, my_bank,
+                           k_max).T.reshape(-1)              # j-major
+    bag = jnp.arange(NB * L, dtype=jnp.int32) % NB
+    return _ct_scatter_call(ct, _dest_slots(rows, n_rows), bag, n_rows,
+                            out_dtype,
                             tile_s=tile_s, interpret=interpret,
                             n_slots=n_slots)
 
@@ -737,10 +795,10 @@ def ct_scatter_csr_pallas(ct: jax.Array, indices: jax.Array,
     indices/seg_ids (T,) the forward's flat stream (entries keep their
     natural stream order within a run — the single-scatter fallback's
     order). -> (n_rows, D)."""
-    valid = indices >= 0
-    row = jnp.where(valid, indices, 0)
-    dest = _dest_slots(row, valid, bank, slot, my_bank, n_rows)
-    return _ct_scatter_call(ct, dest, seg_ids, n_rows, out_dtype,
+    rows = resolve_entries(indices[:, None], bank, slot,
+                           jnp.zeros((1,), jnp.int32), my_bank)[:, 0]
+    return _ct_scatter_call(ct, _dest_slots(rows, n_rows), seg_ids, n_rows,
+                            out_dtype,
                             tile_s=tile_s, interpret=interpret,
                             n_slots=n_slots)
 
@@ -760,12 +818,13 @@ def csr_bag_pallas(table: jax.Array, bank: jax.Array, slot: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(num_bags // tile_b,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((tile_b, D), lambda b, *_: (b, 0)),
         scratch_shapes=_scratch(D, table.dtype, n_slots),
     )
+    args = (indices, seg_ids, offsets_ext, bank, slot, my_bank, table)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_bags, D), table.dtype),
+        out_shape=_out_struct((num_bags, D), table.dtype, args),
         interpret=interpret,
-    )(indices, seg_ids, offsets_ext, bank, slot, my_bank, table)
+    )(*args)

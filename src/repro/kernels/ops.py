@@ -36,14 +36,14 @@ def _pad_batch(idx: jax.Array, tile_b: int) -> tuple[jax.Array, int]:
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
 def embedding_bag(table: jax.Array, idx: jax.Array, *, tile_b: int = 8,
                   interpret: bool | None = None) -> jax.Array:
-    """(V, D) x (B, L) -> (B, D). Pads D to 128 lanes and B to the tile."""
+    """(V, D) x (B, L) -> (B, D). Pads B to the tile (the kernel wrapper
+    lays D out on the 128 lanes)."""
     if interpret is None:
         interpret = not _on_tpu()
-    tpad, d0 = _pad_dim(table)
     ipad, b0 = _pad_batch(idx, tile_b)
-    out = embedding_bag_pallas(tpad, ipad, tile_b=tile_b,
+    out = embedding_bag_pallas(table, ipad, tile_b=tile_b,
                                interpret=bool(interpret))
-    return out[:b0, :d0]
+    return out[:b0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))

@@ -7,7 +7,11 @@ from __future__ import annotations
 
 import jax
 
-from repro.core.compat import make_mesh
+
+def make_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -143,9 +143,12 @@ class CompileProbe:
             self._m_compiles.inc()
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dlrm-rm2")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published config (needs a real "
+                         "accelerator); default: the reduced smoke config")
     ap.add_argument("--requests", type=int, default=512)
     ap.add_argument("--batch", type=int, default=64)
     ap.add_argument("--seed", type=int, default=0)
@@ -256,21 +259,40 @@ def main() -> None:
                          "run, one serve executable) — the CI "
                          "failure-injection contract")
     _add_obs_args(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.backend == "auto":
         args.backend = "tuned"   # auto now means: consult the dispatch cache
+    return args
 
+
+def main(argv: list[str] | None = None) -> dict | None:
+    """Run the CLI; the plain loop returns what it served (``serve_plain``)."""
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    args = parse_args(argv)
     spec = get_arch(args.arch)
     assert spec.family in ("dlrm", "din", "xdeepfm"), "recsys serving CLI"
-    cfg = spec.reduced
+    cfg = spec.config if args.full else spec.reduced
     mod = __import__(f"repro.models.{spec.family}", fromlist=["forward"])
     if args.adaptive:
         assert spec.family == "dlrm", "--adaptive drives the banked super-table"
         return _main_adaptive(args, spec, cfg, mod)
+    return serve_plain(args, spec, cfg, mod)
+
+
+def serve_plain(args, spec, cfg, mod) -> dict:
+    """The plain serve loop: requests -> MicroBatcher -> compiled scoring.
+
+    The step is compiled before the first request arrives, so compilation
+    is set-up time and stays out of the request latencies. Returns what the
+    loop served with: the ``lowered`` and compiled ``serve`` step, its
+    ``params`` and ``tables`` arguments, the ``statics`` they came from,
+    ``compile_s``, and the last ``batch`` with its ``scores``.
+    """
     params, statics = mod.init_params(cfg, jax.random.key(args.seed))
-    from repro.serve.serve_step import build_recsys_serve
+    from repro.serve.serve_step import build_recsys_serve, serve_tables
     backend = args.backend if spec.family == "dlrm" else None
-    serve = jax.jit(build_recsys_serve(mod, cfg, statics, backend=backend))
+    tables = serve_tables(statics)
 
     rng = np.random.default_rng(args.seed)
     from repro.data import synthetic as syn
@@ -285,19 +307,32 @@ def main() -> None:
     proto.pop("label", None)
     pad = {k: v[0] for k, v in proto.items()}
 
+    # the MicroBatcher's batch type: args.batch stacked device rows
+    batch_type = {k: jax.ShapeDtypeStruct((args.batch, *np.shape(v)),
+                                          jnp.asarray(v).dtype)
+                  for k, v in pad.items()}
+    t0 = time.perf_counter()
+    lowered = jax.jit(build_recsys_serve(mod, cfg, statics, backend=backend)
+                      ).lower(params, tables, batch_type)
+    serve = lowered.compile()
+    compile_s = time.perf_counter() - t0
+    print(f"compiled serve step in {compile_s:.2f}s")
+
     tracer, metrics, writer = _setup_obs(args, label=f"serve:{args.arch}")
     mb = MicroBatcher(args.batch, pad, metrics=metrics)
     n_batches = 0
+    last = {}
 
     def run_batch():
         nonlocal n_batches
         with tracer.span("rewrite"):
             reqs, feats_b = mb.next_batch()
         with tracer.span("device_step", batch=n_batches):
-            scores = serve(params, feats_b)
+            scores = serve(params, tables, feats_b)
             jax.block_until_ready(scores)
         mb.complete(reqs)
         n_batches += 1
+        last.update(batch=feats_b, scores=scores)
         if writer is not None:
             writer.maybe_write(n_batches)
 
@@ -314,6 +349,8 @@ def main() -> None:
     print(f"served {len(lat)} requests  p50={p50:.2f}ms "
           f"p99={mb.p99() * 1e3:.2f}ms")
     _finalize_obs(args, tracer, metrics, writer, latencies=mb.latencies)
+    return dict(lowered=lowered, serve=serve, params=params, tables=tables,
+                statics=statics, compile_s=compile_s, **last)
 
 
 def _main_adaptive(args, spec, cfg, mod) -> None:

@@ -120,6 +120,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.backend == "auto":
         args.backend = "tuned"   # auto now means: consult the dispatch cache
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     spec = get_arch(args.arch)
     cfg = spec.config if args.full else spec.reduced

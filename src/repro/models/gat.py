@@ -23,8 +23,6 @@ from repro.core.embedding import DistCtx
 from repro.models.common import dense_init, shard
 from repro.sparse.ops import segment_softmax
 
-from repro.core.compat import shard_map
-
 Array = jax.Array
 
 
@@ -113,7 +111,7 @@ def gat_layer(lw: dict, h_src: Array, h_dst: Array, edge_src: Array,
             msg = z_src[e_src] * (ex / jnp.maximum(denom[e_dst], 1e-20))[..., None]
             return jax.lax.psum(jax.ops.segment_sum(msg, e_dst, n_dst), axes)
 
-        hz = shard_map(
+        hz = jax.shard_map(
             fn, mesh=dist.mesh,
             in_specs=(P(ax), P(ax), P(ax)), out_specs=P(),
         )(edge_src, edge_dst, edge_mask)

@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.compat import shard_map
-
 Array = jax.Array
 
 NEG_INF = -1e30
@@ -334,7 +332,7 @@ def moe_layer_sharded(x: Array, w_router: Array, w_gate: Array, w_up: Array,
         out = jax.lax.psum(out, bank)
         return out.reshape(B_l, S_l, d)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=dist.mesh,
         in_specs=(P(dp, None, None), P(None, None),
                   P(bank, None, None), P(bank, None, None),

@@ -15,18 +15,30 @@ import jax
 import jax.numpy as jnp
 
 
+def serve_tables(statics: dict) -> dict:
+    """The array entries of a family's ``statics`` (remap vectors, field
+    offsets): the ``tables`` argument of ``build_recsys_serve``'s step."""
+    return {k: v for k, v in statics.items() if isinstance(v, jax.Array)}
+
+
 def build_recsys_serve(family_mod, cfg, statics, dist=None,
                        backend: str | None = None):
-    """CTR scoring: forward + sigmoid.
+    """CTR scoring: forward + sigmoid, as ``serve(params, tables, batch)``.
+
+    ``tables`` is ``serve_tables(statics)``. The remap vectors enter as jit
+    arguments because JAX embeds closed-over arrays in the program as
+    constants: 75.5 MB per vector at updlrm-paper's published vocab.
 
     ``backend`` selects the embedding stage-2 implementation for families
     that expose the knob (dlrm: 'jnp' | 'pallas' | 'auto'); None keeps the
     family default.
     """
     kw = {} if backend is None else {"backend": backend}
+    scalars = {k: v for k, v in statics.items() if not isinstance(v, jax.Array)}
 
-    def serve(params, batch):
-        logits = family_mod.forward(cfg, params, statics, batch, dist, **kw)
+    def serve(params, tables, batch):
+        logits = family_mod.forward(cfg, params, {**scalars, **tables}, batch,
+                                    dist, **kw)
         return jax.nn.sigmoid(logits)
     return serve
 

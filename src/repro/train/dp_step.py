@@ -19,8 +19,6 @@ from repro.train import compress as C
 from repro.train import optim as O
 from repro.train.train_step import TrainState
 
-from repro.core.compat import shard_map
-
 P = jax.sharding.PartitionSpec
 
 
@@ -52,7 +50,7 @@ def build_dp_compressed_step(
             lambda x: P(ax, *([None] * (x.ndim - 1))), batch)
 
     def step(state: TrainState, batch):
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local_grads, mesh=mesh,
             in_specs=(P(), P(), batch_specs(batch)),
             out_specs=(P(), P(), P()),

@@ -28,7 +28,8 @@ from typing import Callable
 
 import numpy as np
 
-from repro.tune.dispatch import (CallSignature, DispatchCache, signature)
+from repro.tune.dispatch import (CallSignature, DispatchCache, current_arch,
+                                 signature)
 
 #: (vocab, dim, batch, bag_len, n_fields) — the rectangular lookup shapes.
 #: benchmarks/bench_embedding.py imports this as its CONFIGS, so the bench
@@ -267,9 +268,7 @@ def tune(cases: list[TuneCase] | None = None, *, smoke: bool = False,
         repeats = SMOKE_REPEATS if smoke else DEFAULT_REPEATS
     cand = candidates(smoke)
     meta = {
-        "arch": arch or (f"{jax.default_backend()}-"
-                         + ("compiled" if jax.default_backend() == "tpu"
-                            else "interpret")),
+        "arch": arch or current_arch(),
         "smoke": smoke,
         "repeats": repeats,
         "n_candidates": len(cand),

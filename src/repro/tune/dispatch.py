@@ -200,11 +200,22 @@ def default_cache_path() -> str | None:
     return None
 
 
+def current_arch() -> str:
+    """The platform a sweep measures on and a lookup runs on, as recorded in
+    ``meta.arch``: ``tpu-compiled`` on a TPU, ``<backend>-interpret``
+    elsewhere (off-TPU the kernels run in interpret mode)."""
+    import jax
+    backend = jax.default_backend()
+    return f"{backend}-{'compiled' if backend == 'tpu' else 'interpret'}"
+
+
 def get_cache() -> DispatchCache:
     """The process-wide cache, loaded lazily from ``default_cache_path()``.
-    No file (or an unreadable one) yields an EMPTY cache: every lookup
-    misses and the deterministic defaults apply — 'tuned' degrades to
-    exactly the pre-tuner behavior rather than failing."""
+    No file, an unreadable one, or one measured on another platform
+    (``meta.arch`` != ``current_arch()``) yields an EMPTY cache: every
+    lookup misses and the deterministic defaults apply — 'tuned' degrades
+    to exactly the pre-tuner behavior rather than failing, and interpret-
+    mode timings never pick a backend on the chip."""
     global _CACHE, _LOADED
     with _LOCK:
         if not _LOADED:
@@ -215,6 +226,8 @@ def get_cache() -> DispatchCache:
                     cache = DispatchCache.load(path)
                 except (OSError, ValueError, KeyError, json.JSONDecodeError):
                     cache = None
+            if cache is not None and cache.meta.get("arch") != current_arch():
+                cache = None
             _CACHE = cache if cache is not None else DispatchCache()
             _LOADED = True
         return _CACHE

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.compat import shard_map
 from repro.core.embedding import BankedTable, DistCtx
 from repro.core.partitioning import PartitionPlan
 
@@ -148,7 +147,7 @@ def _migrate_packed_sharded(t: BankedTable, new_plan: PartitionPlan,
                 my = jax.lax.axis_index(bank)
                 return stay_rows(old_local, ob, osl, nb, ns, my)[2]
 
-            return shard_map(
+            return jax.shard_map(
                 fn_local, mesh=dist.mesh,
                 in_specs=(P(bank, None), P(), P(), P(), P()),
                 out_specs=P(bank, None),
@@ -173,7 +172,7 @@ def _migrate_packed_sharded(t: BankedTable, new_plan: PartitionPlan,
                 jnp.where(in_mine[:, None], buf, jnp.zeros((), dtype)),
                 mode="drop")
 
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=dist.mesh,
             in_specs=(P(bank, None), P(), P(), P(), P(), P(), P(), P(), P()),
             out_specs=P(bank, None),
@@ -195,7 +194,7 @@ def _migrate_packed_sharded(t: BankedTable, new_plan: PartitionPlan,
             buf, (my * new_rpb, 0), (new_rpb, D))
         return local + incoming
 
-    return shard_map(
+    return jax.shard_map(
         fn_full, mesh=dist.mesh,
         in_specs=(P(bank, None), P(), P(), P(), P()),
         out_specs=P(bank, None),

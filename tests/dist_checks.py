@@ -31,7 +31,7 @@ def check(name, ok):
 
 
 def mesh42():
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     return make_mesh((4, 2), ("data", "model"))
 
 
@@ -174,7 +174,8 @@ def check_dp_compressed_step():
     mesh = mesh42()
     loss = lambda p, b: D.loss_fn(cfg, p, statics, b)
     opt = adam(1e-2)
-    step_c = build_dp_compressed_step(loss, opt, mesh, ("data", "model"))
+    step_c = jax.jit(build_dp_compressed_step(loss, opt, mesh,
+                                              ("data", "model")))
     state = TrainState.create(params, opt, compress=True)
     state_ref = TrainState.create(params, opt)
     step_r = jax.jit(build_train_step(loss, opt, clip_norm=None))
@@ -454,7 +455,7 @@ def check_degraded_serve_through_failure():
     recovery replan + sharded migration, bit-matches a fresh pack — the
     serve-side contract of launch/serve.py --inject-bank-failure."""
     import dataclasses as dc
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core.embedding import degraded_row_counts
     from repro.workload import migrate_table
     from repro.workload.migrate import permute_packed_rows

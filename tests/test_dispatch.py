@@ -113,6 +113,28 @@ def test_hit_returns_recorded_decision():
     assert cache.hits == 1 and cache.misses == 0
 
 
+@pytest.mark.parametrize("platform,expect_entries", [
+    ("cpu-interpret", True), ("tpu-compiled", False)])
+def test_cache_applies_only_on_its_platform(tmp_path, monkeypatch, platform,
+                                            expect_entries):
+    """A cache measured in interpret mode on the CPU must never pick a
+    backend on the chip: on another platform it loads EMPTY, every lookup
+    misses, and the deterministic rule applies."""
+    import repro.tune.dispatch as D
+    cache = DispatchCache(meta={"arch": "cpu-interpret"})
+    sig = signature("plain", vocab=50, dim=8, batch=4, bag_len=2)
+    cache.record(sig, backend="jnp", tile_b=4, n_slots=3)
+    out = tmp_path / "TUNE_dispatch.json"
+    cache.save(str(out))
+    monkeypatch.setenv(CACHE_ENV, str(out))
+    monkeypatch.setattr(D, "current_arch", lambda: platform)
+    set_cache(None)
+    dec = decide("plain", vocab=50, dim=8, batch=4, bag_len=2,
+                 default_backend="pallas")
+    assert (dec.source == "cache") == expect_entries
+    assert dec.backend == ("jnp" if expect_entries else "pallas")
+
+
 def test_near_miss_is_a_miss():
     cache = DispatchCache()
     cache.record(signature("plain", vocab=50, dim=8, batch=4, bag_len=2),

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.serve.serve_step import MicroBatcher, Request
 
@@ -22,6 +23,32 @@ class TestMicroBatcher:
         mb.complete(reqs2)
         assert len(mb.latencies) == 6
         assert mb.p99() >= 0.0
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """The persistent compile cache goes where $JAX_COMPILATION_CACHE_DIR
+    says, with nothing set in code, and otherwise to one fixed path in the
+    checkout."""
+    import os
+    from repro.launch.compile_cache import ENV, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv(ENV, str(tmp_path))
+    else:
+        monkeypatch.delenv(ENV, raising=False)
+    try:
+        path = enable_compile_cache()
+        if from_env:
+            assert path == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            root = os.path.dirname(os.path.dirname(os.path.abspath(
+                __file__)))
+            assert path == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 class TestDecodeConsistency:

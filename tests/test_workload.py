@@ -305,7 +305,7 @@ class TestMigration:
         on a 1x1 mesh here (the pipe-cleaner; the real 4x2-mesh parity runs
         in tests/dist_checks.py with forced host devices), including the
         no-move short-circuit that drops the collective entirely."""
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.core.embedding import DistCtx
         V, D, banks = 120, 8, 1
         rng = np.random.default_rng(9)
