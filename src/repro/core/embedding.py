@@ -316,6 +316,7 @@ def _pad_bags(flat: Array, tile_b: int) -> tuple[Array, int]:
     return pad_leading(flat, tile_b)
 
 
+@jax.named_scope("relayout")
 def _pad_lanes(table: Array, interpret: bool) -> tuple[Array, int]:
     if interpret:               # no lane constraint off-TPU: skip the copy
         return table, table.shape[-1]
@@ -800,6 +801,7 @@ class DistCtx:
         return int(np.prod([self.mesh.shape[a] for a in self.dp_axes]))
 
 
+@jax.named_scope("lookup")
 def banked_embedding_bag(t: BankedTable, idx: Array, dist: DistCtx | None,
                          *, reduce_bag: bool = True, backend: str = "auto",
                          bwd_backend: str = "auto",
@@ -956,6 +958,7 @@ def _replica_failover_maps(t: ReplicatedTable,
     return bank_flat.reshape(-1), slot_flat.reshape(-1)
 
 
+@jax.named_scope("lookup")
 def replicated_embedding_bag(t: ReplicatedTable, idx: Array,
                              dist: DistCtx | None, *, backend: str = "auto",
                              bwd_backend: str = "auto",
@@ -1027,6 +1030,7 @@ def replicated_embedding_bag(t: ReplicatedTable, idx: Array,
     return _replicated_bag(cfg, t.packed, bank_flat, slot_flat, off, my, idx)
 
 
+@jax.named_scope("lookup")
 def tiered_embedding_bag(fp_packed: Array, tt, idx: Array,
                          dist: DistCtx | None, *, backend: str = "auto",
                          bwd_backend: str = "auto",
@@ -1107,6 +1111,7 @@ def tiered_embedding_bag(fp_packed: Array, tt, idx: Array,
       tt.remap_slot, off, idx)
 
 
+@jax.named_scope("lookup")
 def banked_cache_residual_bag(t: BankedTable, cache: BankedTable,
                               cache_idx: Array, residual_idx: Array,
                               dist: DistCtx | None, *, backend: str = "auto",

@@ -57,5 +57,5 @@ def dot_interaction_pallas(z: jax.Array, *, tile_b: int = 128,
         in_specs=[pl.BlockSpec((tile_b, F, D), lambda b: (b, 0, 0))],
         out_specs=pl.BlockSpec((tile_b, n_pairs_pad), lambda b: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B, n_pairs_pad), z.dtype),
-        interpret=interpret,
+        interpret=interpret, name="updlrm_dot_interaction",
     )(z)

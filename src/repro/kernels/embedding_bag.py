@@ -122,6 +122,7 @@ def replica_of_bag(bag: jax.Array, k_max: int) -> jax.Array:
     return (wang_hash(bag) % jnp.uint32(k_max)).astype(jnp.int32)
 
 
+@jax.named_scope("resolve")
 def resolve_entries(idx: jax.Array, bank: jax.Array, slot: jax.Array,
                     field_offsets: jax.Array, my_bank: jax.Array,
                     k_max: int = 1) -> jax.Array:
@@ -227,6 +228,7 @@ def pad_leading(x: jax.Array, mult: int, fill=-1) -> tuple[jax.Array, int]:
     return x, n
 
 
+@jax.named_scope("relayout")
 def pack_lanes(table: jax.Array) -> tuple[jax.Array, int]:
     """(V, D) -> (lane-dense table, rows per lane row ``pack``).
 
@@ -590,7 +592,7 @@ def tiered_embedding_bag_pallas(payload: jax.Array, scale_bits: jax.Array,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=_out_struct((NB, dim), jnp.float32, args),
-        interpret=interpret,
+        interpret=interpret, name="updlrm_tiered_bag",
     )(*args)
 
 
@@ -620,7 +622,7 @@ def embedding_bag_pallas(table: jax.Array, idx: jax.Array, *,
         out_specs=pl.BlockSpec((tile_b, W), lambda b: (b, 0)),
         scratch_shapes=_scratch(W, table.dtype, n_slots),
         out_shape=_out_struct((B, W), table.dtype, (idx, packed)),
-        interpret=interpret,
+        interpret=interpret, name="updlrm_bag",
     )(idx, packed)
     return out[:, :D]
 
@@ -653,7 +655,7 @@ def plain_cache_bag_pallas(emt: jax.Array, cache: jax.Array,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=_out_struct((B, D), emt.dtype, args),
-        interpret=interpret,
+        interpret=interpret, name="updlrm_plain_cache_bag",
     )(*args)
 
 
@@ -689,7 +691,7 @@ def fused_cache_bag_pallas(emt: jax.Array, cache: jax.Array,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=_out_struct((B, D), emt.dtype, args),
-        interpret=interpret,
+        interpret=interpret, name="updlrm_fused_cache_bag",
     )(*args)
 
 
@@ -744,7 +746,7 @@ def _ct_scatter_call(ct: jax.Array, dest: jax.Array, bags: jax.Array,
         # d_table aliases a zeros input (operand 6 = 5 scalars + ct): only
         # touched rows are DMA'd, the rest must already BE zero
         input_output_aliases={6: 0},
-        interpret=interpret,
+        interpret=interpret, name="updlrm_ct_scatter",
     )(*args, zeros)
     return out[:, :d]
 
@@ -826,5 +828,5 @@ def csr_bag_pallas(table: jax.Array, bank: jax.Array, slot: jax.Array,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=_out_struct((num_bags, D), table.dtype, args),
-        interpret=interpret,
+        interpret=interpret, name="updlrm_csr_bag",
     )(*args)

@@ -319,7 +319,7 @@ def serve_plain(args, spec, cfg, mod) -> dict:
     print(f"compiled serve step in {compile_s:.2f}s")
 
     tracer, metrics, writer = _setup_obs(args, label=f"serve:{args.arch}")
-    mb = MicroBatcher(args.batch, pad, metrics=metrics)
+    mb = MicroBatcher(args.batch, pad, metrics=metrics, tracer=tracer)
     n_batches = 0
     last = {}
 
@@ -468,7 +468,8 @@ def _main_adaptive(args, spec, cfg, mod) -> None:
                 "sparse": sparse}
 
     pad = one_request(-1)
-    mb = MicroBatcher(args.batch, pad, observer=observe, metrics=metrics)
+    mb = MicroBatcher(args.batch, pad, observer=observe, metrics=metrics,
+                      tracer=tracer)
     verify: dict = {}
     state = {"warm_compiles": None, "n_batches": 0}
 
@@ -640,7 +641,7 @@ def _main_adaptive_replicated(args, spec, cfg, mod) -> None:
                 "sparse": sparse}
 
     mb = MicroBatcher(args.batch, one_request(-1), observer=observe,
-                      metrics=metrics)
+                      metrics=metrics, tracer=tracer)
     verify: dict = {}
     state = {"warm_compiles": None, "n_batches": 0}
 
@@ -828,7 +829,7 @@ def _main_adaptive_fault(args, spec, cfg, mod) -> None:
                 "sparse": sparse}
 
     mb = MicroBatcher(args.batch, one_request(-1), observer=observe,
-                      metrics=metrics)
+                      metrics=metrics, tracer=tracer)
     st = {"batch": 0, "handled_dead": frozenset(), "penalized": False,
           "fail_batch": None, "recover_batch": None,
           "confine_ok": True, "confine_checked": 0,
@@ -1053,7 +1054,7 @@ def _main_adaptive_cached(args, spec, cfg, mod) -> None:
                 "sparse": sparse}
 
     mb = MicroBatcher(args.batch, one_request(-1), observer=observe,
-                      metrics=metrics)
+                      metrics=metrics, tracer=tracer)
     verify: dict = {}
     state = {"warm_compiles": None, "n_batches": 0}
 

@@ -219,13 +219,22 @@ def forward(cfg: DLRMConfig, params: dict, statics: dict, batch: dict,
             t, sparse, dist, backend=backend, bwd_backend=bwd_backend,
             field_offsets=statics["field_offsets"], bank_live=bank_live)
     emb = shard(emb, dist, dp(dist), None, None).astype(cfg.dtype)
+    return _ctr_head(cfg, params, dense, emb)
 
-    x = mlp_apply(params["bot"], dense.astype(cfg.dtype))        # (B, D)
-    z = jnp.concatenate([x[:, None], emb], axis=1)               # (B, F+1, D)
-    inter = dot_interaction(z)                                   # (B, P)
-    feat = jnp.concatenate([inter, x], axis=-1)
-    logit = mlp_apply(params["top"], feat)[:, 0]
-    return logit
+
+def _ctr_head(cfg: DLRMConfig, params: dict, dense: Array,
+              emb: Array) -> Array:
+    """The CTR compute after the lookup: bottom MLP over the dense
+    features, pairwise dot interaction with the (B, F, D) bags, top MLP.
+    Returns logits (B,)."""
+    with jax.named_scope("bottom_mlp"):
+        x = mlp_apply(params["bot"], dense.astype(cfg.dtype))    # (B, D)
+    with jax.named_scope("interaction"):
+        z = jnp.concatenate([x[:, None], emb], axis=1)           # (B, F+1, D)
+        inter = dot_interaction(z)                               # (B, P)
+        feat = jnp.concatenate([inter, x], axis=-1)
+    with jax.named_scope("top_mlp"):
+        return mlp_apply(params["top"], feat)[:, 0]
 
 
 def forward_cached(cfg: DLRMConfig, params: dict, statics: dict,
@@ -256,11 +265,7 @@ def forward_cached(cfg: DLRMConfig, params: dict, statics: dict,
                                     backend=backend,
                                     bwd_backend=bwd_backend,
                                     bank_live=bank_live)
-    x = mlp_apply(params["bot"], dense.astype(cfg.dtype))
-    z = jnp.concatenate([x[:, None], emb], axis=1)
-    inter = dot_interaction(z)
-    feat = jnp.concatenate([inter, x], axis=-1)
-    return mlp_apply(params["top"], feat)[:, 0]
+    return _ctr_head(cfg, params, dense, emb)
 
 
 def bce_loss(logits: Array, labels: Array) -> Array:

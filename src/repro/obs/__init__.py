@@ -4,7 +4,9 @@ The package ROOT is dependency-free (stdlib only — producers include the
 deliberately-jax-free ``repro.dist.fault`` and the numpy-only benches):
 
 * ``tracing``        — ``Tracer.span("device_step")`` host-side spans,
-                       instants, and ``counter`` gauge samples;
+                       also opened as ``jax.profiler.TraceAnnotation``s
+                       once jax is loaded, instants, and ``counter``
+                       gauge samples;
                        ``trace_export.write_chrome_trace`` emits
                        Perfetto-loadable Chrome-trace JSON ('X'/'i'/'C').
 * ``metrics``        — typed ``Counter``/``Gauge``/``Histogram`` (fixed

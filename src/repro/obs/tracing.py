@@ -1,11 +1,19 @@
-"""Host-side structured tracing: named spans -> Chrome-trace/Perfetto JSON.
+"""Structured tracing: named spans on the host clock and the profiler's.
 
 The serve/train loops are host-driven: every micro-batch is a sequence of
 host stages (assemble/rewrite, jitted device step, telemetry, maybe a
 replan+migrate+swap) and the p99 question is always "which stage did the
-spike live in". ``Tracer.span`` times those stages with plain
-``perf_counter`` reads; ``trace_export.write_chrome_trace`` turns the record
-list into the Chrome trace-event JSON Perfetto loads directly.
+spike live in". ``Tracer.span`` answers it on two clocks at once:
+
+* **The profiler's.** Every span opens a ``jax.profiler.TraceAnnotation``
+  of its name and ``args``, so any jax profiler session (``jax.profiler.trace``,
+  XProf, the benchmark's ``--trace 1``) shows it on the host's track beside
+  the device ops it dispatched. Outside a session the annotation records
+  nothing and costs about as much as an empty ``with``.
+* **The tracer's own record.** With ``enabled`` set, the span is also timed
+  with ``perf_counter`` into ``records``, which
+  ``trace_export.write_chrome_trace`` turns into the Chrome trace-event JSON
+  Perfetto loads directly (``--trace-out``).
 
 Contracts:
 
@@ -13,12 +21,17 @@ Contracts:
   caller decides where device work is forced (the serve loops already call
   ``jax.block_until_ready`` at the device-step boundary); a span around an
   UN-synced dispatch measures dispatch cost, which is sometimes exactly what
-  you want. Nothing here touches jax, so tracing a jit'd step cannot add
-  executables (tests/test_obs.py pins the zero-recompile assert).
-* **Near-zero when disabled.** ``Tracer(enabled=False)`` (or the shared
-  ``NULL_TRACER``) short-circuits ``span`` to a no-yield-cost context
-  manager, so instrumented code paths keep one shape whether or not
-  ``--trace-out`` was passed.
+  you want. An annotation is not traced into a jitted function, so tracing a
+  jit'd step cannot add executables (tests/test_obs.py pins the
+  zero-recompile assert).
+* **``enabled`` gates the record, not the annotation.** ``Tracer(enabled=
+  False)`` (or the shared ``NULL_TRACER``) records nothing but still
+  annotates, so instrumented code paths keep one shape whether or not
+  ``--trace-out`` was passed, and reach a profiler trace either way.
+* **jax-free import.** This module imports no jax: producers include the
+  deliberately jax-free ``repro.dist.fault``. A span annotates only once
+  ``jax.profiler`` is loaded (``import jax`` loads it); before that no
+  profiler session can exist, and a span is a plain context.
 * **Thread-correct nesting.** The open-span stack is thread-local; records
   carry the thread id so a future background-planner thread shows up as its
   own Perfetto track.
@@ -27,6 +40,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import sys
 import threading
 import time
 
@@ -65,6 +79,15 @@ class CounterRecord:
     values: dict
 
 
+def _annotation(name: str, args: dict):
+    """The profiler annotation of a span: a ``TraceAnnotation`` once jax's
+    profiler is loaded, else a context that does nothing."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None:
+        return contextlib.nullcontext()
+    return prof.TraceAnnotation(name, **args)
+
+
 class Tracer:
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
@@ -81,19 +104,24 @@ class Tracer:
             st = self._local.stack = []
         return st
 
-    @contextlib.contextmanager
     def span(self, name: str, **args):
         """Time a host stage. Nestable; ``args`` land in the trace event's
-        ``args`` payload (keep them small and JSON-serializable)."""
+        ``args`` payload and the annotation's (keep them small and
+        JSON-serializable)."""
+        ann = _annotation(name, args)
         if not self.enabled:
-            yield
-            return
+            return ann
+        return self._recorded(name, args, ann)
+
+    @contextlib.contextmanager
+    def _recorded(self, name: str, args: dict, ann):
         stack = self._stack()
         depth = len(stack)
         stack.append(name)
         t0 = time.perf_counter()
         try:
-            yield
+            with ann:
+                yield
         finally:
             t1 = time.perf_counter()
             stack.pop()

@@ -253,11 +253,18 @@ class MicroBatcher:
     ``observer(feats, n_real)`` on every assembled batch, where ``n_real`` is
     the count of genuine (non-pad) requests — pad rows replicate a prototype
     request and must not be counted as traffic.
+
+    ``tracer`` (an ``obs.Tracer``; ``None`` is ``NULL_TRACER``) spans each
+    ``next_batch``: ``serve.batch`` the whole call (args ``batch``, its
+    sequence number, and ``n_real``), ``serve.h2d`` each host-to-device
+    hand-over of request data, ``serve.stack`` the combining of one key's
+    rows into the batch array. The spans reach any jax profiler session
+    whether or not the tracer records.
     """
 
     def __init__(self, batch_size: int, pad_request: dict,
                  observer: Callable[[dict, int], None] | None = None,
-                 metrics=None):
+                 metrics=None, tracer=None):
         self.batch_size = batch_size
         self.pad_request = pad_request
         self.observer = observer
@@ -266,6 +273,11 @@ class MicroBatcher:
         if metrics is None:
             from repro.obs import MetricRegistry
             metrics = MetricRegistry()
+        if tracer is None:
+            from repro.obs import NULL_TRACER
+            tracer = NULL_TRACER
+        self.tracer = tracer
+        self._n_batches = 0
         self._m_requests = metrics.counter("serve.requests_total",
                                            "completed (non-pad) requests")
         self._m_latency = metrics.histogram(
@@ -278,16 +290,24 @@ class MicroBatcher:
         return len(self.queue) > 0
 
     def next_batch(self) -> tuple[list[Request], dict]:
-        reqs = [self.queue.popleft()
-                for _ in range(min(self.batch_size, len(self.queue)))]
-        feats = {}
-        n_pad = self.batch_size - len(reqs)
-        for key in self.pad_request:
-            rows = [r.features[key] for r in reqs]
-            rows += [self.pad_request[key]] * n_pad
-            feats[key] = jnp.stack([jnp.asarray(r) for r in rows])
-        if self.observer is not None:
-            self.observer(feats, len(reqs))
+        span = self.tracer.span
+        n_real = min(self.batch_size, len(self.queue))
+        with span("serve.batch", batch=self._n_batches, n_real=n_real):
+            self._n_batches += 1
+            reqs = [self.queue.popleft() for _ in range(n_real)]
+            feats = {}
+            n_pad = self.batch_size - n_real
+            for key in self.pad_request:
+                rows = [r.features[key] for r in reqs]
+                rows += [self.pad_request[key]] * n_pad
+                dev = []
+                for r in rows:
+                    with span("serve.h2d"):
+                        dev.append(jnp.asarray(r))
+                with span("serve.stack"):
+                    feats[key] = jnp.stack(dev)
+            if self.observer is not None:
+                self.observer(feats, n_real)
         return reqs, feats
 
     def complete(self, reqs: list[Request]) -> None:

@@ -107,3 +107,32 @@ def test_banked_stage2_fusion_equivalence():
         total += np.asarray(part)
     want_total = REF.embedding_bag_ref(jnp.asarray(table), jnp.asarray(idx))
     np.testing.assert_allclose(total, want_total, atol=1e-4)
+
+
+def _off_chip_kernel_cases():
+    """The two kernels that do not lower for a v5e yet, as (function, its
+    arguments' shapes): their names are checked in the jaxpr instead of the
+    compiled text (tests/test_tpu_compile.py names the others)."""
+    from repro.kernels.dot_interaction import dot_interaction_pallas
+    from repro.kernels.embedding_bag import tiered_embedding_bag_pallas
+    i32 = jnp.int32
+    V, D, NB, L = 64, 16, 8, 4
+    S = jax.ShapeDtypeStruct
+    return {
+        "updlrm_tiered_bag": (
+            lambda p, sc, t, b, s, o, m, i: tiered_embedding_bag_pallas(
+                p, sc, t, b, s, o, m, i, dim=D, interpret=True),
+            [S((V, 2 * D), jnp.int8), S((V,), i32), S((V,), i32),
+             S((V,), i32), S((V,), i32), S((2,), i32), S((1,), i32),
+             S((NB, L), i32)]),
+        "updlrm_dot_interaction": (
+            lambda z: dot_interaction_pallas(z, interpret=True),
+            [S((NB, 4, D), jnp.float32)]),
+    }
+
+
+@pytest.mark.parametrize("name", ["updlrm_tiered_bag",
+                                  "updlrm_dot_interaction"])
+def test_off_chip_kernels_carry_their_names(name):
+    fn, args = _off_chip_kernel_cases()[name]
+    assert f"name={name}" in str(jax.make_jaxpr(fn)(*args))

@@ -18,6 +18,9 @@ What is pinned here and why it matters:
   of one configuration must produce structurally identical documents.
 * Zero-recompile — tracing a jit'd step must not add executables; the
   whole obs layer is host-clock-only by contract.
+* Profiler spans — every span is also a ``jax.profiler.TraceAnnotation``,
+  so a profiler session sees it by name whether or not the tracer records;
+  the package root still imports, and spans still work, without jax.
 """
 import json
 import math
@@ -214,6 +217,57 @@ class TestTracer:
             tr.instant("b")
         assert tr.records == [] and tr.instants == []
         assert NULL_TRACER.enabled is False
+
+    def test_span_and_child_reach_the_profiler(self, host_trace):
+        """Spans go out as ``TraceAnnotation``s: a profiler session sees
+        them by name on the host's plane, the child inside its parent,
+        with the span's args as the event's stats."""
+        tr = Tracer()
+
+        def work():
+            with tr.span("obs.parent", batch=7):
+                with tr.span("obs.child"):
+                    pass
+        _, spans = host_trace(work)
+        ((p0, p1, stats),) = spans["obs.parent"]
+        ((c0, c1, _),) = spans["obs.child"]
+        assert p0 <= c0 <= c1 <= p1
+        assert stats == {"batch": 7}
+        assert tr.span_names() == {"obs.parent", "obs.child"}
+
+    def test_disabled_tracer_annotates_but_records_nothing(self,
+                                                           host_trace):
+        tr = Tracer(enabled=False)
+
+        def work():
+            with tr.span("obs.quiet", step=1):
+                pass
+            with NULL_TRACER.span("obs.null"):
+                pass
+        _, spans = host_trace(work)
+        assert len(spans["obs.quiet"]) == 1 and len(spans["obs.null"]) == 1
+        assert tr.records == [] and NULL_TRACER.records == []
+
+    def test_obs_imports_and_spans_without_jax(self):
+        """The package root stays jax-free (``repro.dist.fault`` produces
+        spans): with jax blocked it imports, and a span is a plain
+        context."""
+        import subprocess
+        import sys
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        code = ("import sys; sys.modules['jax'] = None\n"
+                "from repro.obs import NULL_TRACER, Tracer\n"
+                "tr = Tracer()\n"
+                "with tr.span('a', k=1), NULL_TRACER.span('b'):\n"
+                "    pass\n"
+                "assert tr.span_names() == {'a'}\n"
+                "assert 'jax.profiler' not in sys.modules\n"
+                "print('ok')\n")
+        env = {**os.environ, "PYTHONPATH": os.path.abspath(src)}
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.strip() == "ok"
 
     def test_total_us_sums_same_name(self):
         tr = Tracer()

@@ -24,6 +24,32 @@ class TestMicroBatcher:
         assert len(mb.latencies) == 6
         assert mb.p99() >= 0.0
 
+    def test_next_batch_spans_each_transfer_and_stack(self, host_trace):
+        """One batch of B=4 over 2 keys: one ``serve.batch`` span with its
+        args, 2 x 4 ``serve.h2d`` and 2 ``serve.stack`` spans inside it,
+        and the same feature arrays as the rows stacked on the host."""
+        rng = np.random.default_rng(0)
+        pad = {"dense": np.zeros(3, np.float32),
+               "sparse": np.full((2, 5), -1, np.int32)}
+        rows = [{"dense": rng.standard_normal(3).astype(np.float32),
+                 "sparse": rng.integers(0, 9, (2, 5)).astype(np.int32)}
+                for _ in range(3)]
+        mb = MicroBatcher(batch_size=4, pad_request=pad)
+        for i, f in enumerate(rows):
+            mb.submit(Request(rid=i, features=f))
+        (reqs, feats), spans = host_trace(mb.next_batch)
+        assert len(reqs) == 3
+        ((b0, b1, stats),) = spans["serve.batch"]
+        assert stats == {"batch": 0, "n_real": 3}
+        assert len(spans["serve.h2d"]) == 8
+        assert len(spans["serve.stack"]) == 2
+        for s0, s1, _ in spans["serve.h2d"] + spans["serve.stack"]:
+            assert b0 <= s0 <= s1 <= b1
+        for key in pad:
+            want = np.stack([f[key] for f in rows] + [pad[key]])
+            assert feats[key].dtype == want.dtype
+            np.testing.assert_array_equal(np.asarray(feats[key]), want)
+
 
 @pytest.mark.parametrize("from_env", [True, False])
 def test_compile_cache_dir(monkeypatch, tmp_path, from_env):

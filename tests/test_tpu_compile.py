@@ -74,14 +74,14 @@ def test_banked_bag_kernel_full_vocab(one_chip, k_max):
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < HBM_BYTES
 
 
-def test_serve_step_full_width(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def serve_step_full(one_chip):
     """The jitted updlrm-paper serve step (launch/serve.py --full), built
-    from shapes: it fits one chip and runs the compiled bag kernel."""
+    from shapes and compiled once for the tests below: (lowered,
+    compiled)."""
     import repro.core.embedding as E
     from repro.models import dlrm
     from repro.serve.serve_step import build_recsys_serve
-    # off the chip the wrappers would pick interpret mode; compile for it
-    monkeypatch.setattr(E, "_default_interpret", lambda interpret: False)
     cfg, S = _paper(), _sds(one_chip)
     V, B = cfg.total_vocab, 64
     params = jax.tree.map(
@@ -94,18 +94,48 @@ def test_serve_step_full_width(one_chip, monkeypatch):
     statics = {"n_banks": 1, "rows_per_bank": V, **tables}
     batch = {"dense": S((B, cfg.n_dense), jnp.float32),
              "sparse": S((B, cfg.n_sparse, cfg.multi_hot), jnp.int32)}
-    serve = jax.jit(build_recsys_serve(dlrm, cfg, statics, backend="pallas"))
-    lowered = serve.lower(params, tables, batch)
+    with pytest.MonkeyPatch.context() as mp:
+        # off the chip the wrappers would pick interpret mode; compile for it
+        mp.setattr(E, "_default_interpret", lambda interpret: False)
+        serve = jax.jit(build_recsys_serve(dlrm, cfg, statics,
+                                           backend="pallas"))
+        lowered = serve.lower(params, tables, batch)
+    return lowered, lowered.compile()
+
+
+def test_serve_step_full_width(serve_step_full):
+    """It fits one chip and runs the compiled bag kernel."""
+    lowered, compiled = serve_step_full
     assert "tpu_custom_call" in lowered.as_text()
-    m = lowered.compile().memory_analysis()
+    m = compiled.memory_analysis()
     peak = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert peak < HBM_BYTES, peak
 
 
+def test_serve_step_names_the_bag_kernel(serve_step_full):
+    """The kernel's HLO instruction carries the ``pallas_call``'s name, so
+    a profiler's ``XLA Ops`` event for it starts ``%updlrm_bag``."""
+    import re
+    text = serve_step_full[1].as_text()
+    kernels = re.findall(r"^\s*(%\S+) = .*custom_call_target="
+                         r"\"tpu_custom_call\"", text, re.M)
+    assert len(kernels) == 1 and kernels[0].startswith("%updlrm_bag")
+
+
+@pytest.mark.parametrize("scope", ["/lookup/relayout/", "/lookup/resolve/",
+                                   "/bottom_mlp/", "/interaction/",
+                                   "/top_mlp/"])
+def test_serve_step_op_names_carry_scopes(serve_step_full, scope):
+    import re
+    op_names = re.findall(r'op_name="([^"]*)"', serve_step_full[1].as_text())
+    assert any(scope in n for n in op_names), scope
+
+
 def _small_kernel_cases(S):
     """The kernels off the main path, at a small vocab: they keep their
-    scalar-prefetched remaps, so they must at least keep lowering."""
+    scalar-prefetched remaps, so they must at least keep lowering. Each
+    case is (function, argument types, the kernel's name)."""
     from repro.kernels import embedding_bag as K
     i32, f32 = jnp.int32, jnp.float32
     V, D, NB, L, C = 4096, 128, 64, 16, 512
@@ -115,27 +145,36 @@ def _small_kernel_cases(S):
                 e, c, eb, es, cb, cs, m, ci, ri),
             [S((V, D), f32), S((C, D), f32), S((V,), i32), S((V,), i32),
              S((C,), i32), S((C,), i32), S((1,), i32), S((NB, 4), i32),
-             S((NB, L), i32)]),
+             S((NB, L), i32)], "updlrm_fused_cache_bag"),
         "plain_cache": (
             lambda e, c, ci, ri: K.plain_cache_bag_pallas(e, c, ci, ri),
             [S((V, D), f32), S((C, D), f32), S((NB, 4), i32),
-             S((NB, L), i32)]),
+             S((NB, L), i32)], "updlrm_plain_cache_bag"),
         "ct_scatter": (
             lambda ct, i, b, s, o, m: K.ct_scatter_bag_pallas(
                 ct, i, b, s, o, m, V, f32),
             [S((NB, D), f32), S((NB, L), i32), S((V,), i32), S((V,), i32),
-             S((4,), i32), S((1,), i32)]),
+             S((4,), i32), S((1,), i32)], "updlrm_ct_scatter"),
         "csr": (
             lambda t, b, s, m, ind, seg, off: K.csr_bag_pallas(
                 t, b, s, m, ind, seg, off, NB),
             [S((V, D), f32), S((V,), i32), S((V,), i32), S((1,), i32),
-             S((NB * L,), i32), S((NB * L,), i32), S((NB + 1,), i32)]),
+             S((NB * L,), i32), S((NB * L,), i32), S((NB + 1,), i32)],
+            "updlrm_csr_bag"),
     }
 
 
-@pytest.mark.parametrize("name", ["fused_cache", "plain_cache", "ct_scatter",
-                                  "csr"])
+SMALL_KERNELS = ["fused_cache", "plain_cache", "ct_scatter", "csr"]
+
+
+@pytest.mark.parametrize("name", SMALL_KERNELS)
 def test_other_kernels_lower(one_chip, name):
-    fn, args = _small_kernel_cases(_sds(one_chip))[name]
+    fn, args, _ = _small_kernel_cases(_sds(one_chip))[name]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", SMALL_KERNELS)
+def test_other_kernels_carry_their_names(one_chip, name):
+    fn, args, kernel = _small_kernel_cases(_sds(one_chip))[name]
+    assert kernel in jax.jit(fn).lower(*args).as_text()
