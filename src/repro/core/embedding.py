@@ -71,13 +71,14 @@ def _resolve_backend(backend: str) -> str:
 def _dispatch(path: str, *, vocab: int, dim: int, batch: int, bag_len,
               n_fields: int = 1, k_max: int = 1, tier_mix: str = "none",
               bwd_backend: str = "auto", tile_b: int,
-              n_slots: int) -> tuple[str, int, int]:
+              n_slots: int | None) -> tuple[str, int, int | None]:
     """Resolve ``backend='tuned'``: look the call signature up in the
     persisted dispatch cache (repro.tune, TUNE_dispatch.json) and return
     (backend, tile_b, n_slots) — the measured decision on a hit, today's
-    defaults (the caller's tile_b/n_slots + the pre-tuner auto rule) on a
-    miss. Shapes are static under jit, so this runs at trace time: a pure
-    host dict lookup, deterministic per shape, zero recompiles."""
+    defaults (the caller's tile_b/n_slots, None left to the kernel, + the
+    pre-tuner auto rule) on a miss. Shapes are static under jit, so this
+    runs at trace time: a pure host dict lookup, deterministic per shape,
+    zero recompiles."""
     from repro.tune.dispatch import decide
     d = decide(path, vocab=vocab, dim=dim, batch=batch, bag_len=bag_len,
                n_fields=n_fields, k_max=k_max, tier_mix=tier_mix,
@@ -334,7 +335,7 @@ def _pallas_bag(cfg: tuple, packed: Array, bank: Array, slot: Array,
     — the unsharded path, where slot is the flat remap). ``bwd`` selects the
     custom_vjp backward: 'pallas' = the sorted-run scatter kernel, 'jnp' =
     the XLA segment-scan scatter. ``n_slots`` is the row-DMA pipeline depth
-    (fwd and bwd kernels alike).
+    (fwd and bwd kernels alike; None: each kernel's own default).
     """
     from repro.kernels.embedding_bag import banked_embedding_bag_pallas
     tile_b, interpret, _, n_slots = cfg
@@ -806,7 +807,7 @@ def banked_embedding_bag(t: BankedTable, idx: Array, dist: DistCtx | None,
                          *, reduce_bag: bool = True, backend: str = "auto",
                          bwd_backend: str = "auto",
                          field_offsets: Array | None = None,
-                         tile_b: int = 8, n_slots: int = 2,
+                         tile_b: int = 8, n_slots: int | None = None,
                          interpret: bool | None = None,
                          bank_live: Array | None = None,
                          with_traffic: bool = False):
@@ -831,6 +832,8 @@ def banked_embedding_bag(t: BankedTable, idx: Array, dist: DistCtx | None,
     batch, replicated across banks (stage 1); each bank computes its partial
     with the selected ``backend`` (stage 2); psum over the bank axis (stage 3).
 
+    ``n_slots`` is the pallas kernel's row-copy ring depth; None leaves it
+    to the kernel (``kernels.embedding_bag.bag_ring_depth``).
     ``backend='tuned'`` resolves (backend, tile_b, n_slots) through the
     persisted dispatch cache at trace time (repro.tune); a cache miss is the
     deterministic 'auto' default with the caller's tile_b/n_slots.
@@ -963,7 +966,7 @@ def replicated_embedding_bag(t: ReplicatedTable, idx: Array,
                              dist: DistCtx | None, *, backend: str = "auto",
                              bwd_backend: str = "auto",
                              field_offsets: Array | None = None,
-                             tile_b: int = 8, n_slots: int = 2,
+                             tile_b: int = 8, n_slots: int | None = None,
                              interpret: bool | None = None,
                              bank_live: Array | None = None,
                              with_traffic: bool = False):

@@ -5,11 +5,12 @@ The table(s) stay in HBM (`pl.ANY`); the HBM row of every entry is known
 from SMEM before vector memory is touched (the banked forward gets each
 tile's resolved rows as an SMEM block, the other kernels scalar-prefetch
 their indices and row->(bank, slot) remap vectors); rows stream HBM->VMEM
-through an N-slot rotation of `pltpu.make_async_copy` DMAs (`n_slots`, default 2 =
-classic ping-pong: up to N-1 copies are in flight while entry e is being
-accumulated — the pipeline depth the autotuner sweeps). Each grid step owns a tile of
-bags and writes only the reduced (tile_b, D) block — the (B*L, D) gathered
-matrix a naive XLA gather would materialize never exists.
+through an N-slot ring of `pltpu.make_async_copy` DMAs (`n_slots`: the
+ring's depth, the pipeline depth the autotuner sweeps — `BAG_RING_DEPTH`
+in the compiled forward ``updlrm_bag``, the two-slot ping-pong elsewhere).
+Each grid step owns a tile of bags and writes only the reduced (tile_b, D)
+block — the (B*L, D) gathered matrix a naive XLA gather would materialize
+never exists.
 
 Entry resolution:
   * per-field row offsets      — bag b belongs to field b % n_fields; its raw
@@ -41,6 +42,7 @@ TPU constants.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -49,8 +51,29 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 # ---------------------------------------------------------------------------
-# double-buffered row-DMA accumulate
+# N-slot row-DMA accumulate
 # ---------------------------------------------------------------------------
+
+#: Row-copy ring slots of the compiled ``updlrm_bag`` kernel. One row copy
+#: is a few hundred ns of HBM latency, so the two-slot ping-pong waited on
+#: every entry; a TPU v5e sweep at the serving and bulk-scoring shapes chose
+#: this depth (PERF.md).
+BAG_RING_DEPTH = 32
+#: Copies ``updlrm_bag`` waits on at once, whose rows it then rotates and
+#: adds back to back (``_ring_accumulate``).
+BAG_GROUP = 8
+
+
+def bag_ring_depth(n_slots: int | None, n_entries: int,
+                   interpret: bool) -> int:
+    """The ``updlrm_bag`` ring depth: an explicit ``n_slots`` wins; else
+    the two-slot ping-pong in interpret mode (the CPU tests trace the depth
+    they always have) and ``BAG_RING_DEPTH`` compiled, capped at a tile's
+    ``n_entries`` so a small tile primes no copy it cannot use."""
+    if n_slots is not None:
+        return n_slots
+    return 2 if interpret else min(BAG_RING_DEPTH, n_entries)
+
 
 def _dma_accumulate(acc, table_ref, buf, sem, start, end, src_fn, meta_fn,
                     row_fn=None):
@@ -100,6 +123,66 @@ def _dma_accumulate(acc, table_ref, buf, sem, start, end, src_fn, meta_fn,
         return acc + jnp.where(acc_rows == bag_local, row, 0.0)
 
     return jax.lax.fori_loop(start, end, body, acc)
+
+
+def _ring_accumulate(acc, table_ref, buf, sem, n, src_fn, take_fn,
+                     group: int):
+    """Accumulate table rows for a tile's ``n`` entries (static) into
+    per-bag sums, ``group`` entries at a time.
+
+    ``src_fn(e)`` -> table row to fetch; ``take_fn(e, raw)`` -> (bag_local,
+    row): the accumulator row, and the fp32 row to add from the copied raw
+    row, zero where the entry is masked.
+
+    The ``buf.shape[0]`` (1, W) VMEM slots form ``r = n_slots // group``
+    slot groups; group g of entries lands in slot group ``g % r`` and
+    signals its one semaphore, so ``r - 1`` groups of copies are in flight
+    while one is consumed. Iteration g waits once for all of group g's
+    copies, loads its rows, refills the slot group emptied one iteration
+    earlier with group ``g + r - 1``, and then rotates and adds the rows:
+    the rotations overlap each other and the refill's copy issue (on a
+    v5e, refilling before the wait instead cost 9% more at depth 32). The
+    last ``r - 1`` groups, with nothing left to refill, run in a loop of
+    their own, so no branch splits that block. The rows are added in entry
+    order, so the sums do not depend on the depth or the group.
+    """
+    r = buf.shape[0] // group
+    assert r >= 2 and n % group == 0, (buf.shape[0], group, n)
+    n_groups = n // group
+
+    def start(g):
+        s0 = (g % r) * group
+        for k in range(group):
+            pltpu.make_async_copy(
+                table_ref.at[pl.ds(src_fn(g * group + k), 1), :],
+                buf.at[s0 + k], sem.at[g % r]).start()
+
+    for g in range(min(r - 1, n_groups)):
+        start(g)
+
+    acc_rows = jax.lax.broadcasted_iota(jnp.int32, (acc.shape[0], 1), 0)
+
+    def body(g, acc, refill):
+        s0 = (g % r) * group
+        # a DMA semaphore counts bytes: a descriptor the size of the
+        # group's slots waits for all of its copies at once
+        pltpu.make_async_copy(buf.at[pl.ds(s0, group)],
+                              buf.at[pl.ds(s0, group)], sem.at[g % r]).wait()
+        raws = [buf[s0 + k] for k in range(group)]          # (1, W) each
+        if refill:
+            start(g + r - 1)
+        for k, raw in enumerate(raws):
+            bag_local, row = take_fn(g * group + k, raw)
+            # masked add over the tile's rows: Mosaic cannot lower a
+            # dynamic-row update of a loop-carried value; others add +0.0
+            acc = acc + jnp.where(acc_rows == bag_local, row, 0.0)
+        return acc
+
+    split = max(n_groups - (r - 1), 0)
+    acc = jax.lax.fori_loop(0, split, functools.partial(body, refill=True),
+                            acc)
+    return jax.lax.fori_loop(split, n_groups,
+                             functools.partial(body, refill=False), acc)
 
 
 def wang_hash(x: jax.Array) -> jax.Array:
@@ -254,7 +337,7 @@ def pack_lanes(table: jax.Array) -> tuple[jax.Array, int]:
 # ---------------------------------------------------------------------------
 
 def _plain_bag_kernel(idx_ref, table_ref, out_ref, buf, sem, *,
-                      tile_b: int, bag_len: int, pack: int):
+                      tile_b: int, bag_len: int, pack: int, group: int):
     """``idx_ref`` is this tile's (tile_b, bag_len) SMEM block of table rows
     (-1 skips), so SMEM use scales with the tile, not the batch.
     ``table_ref`` is the ``pack_lanes`` layout: each entry DMAs its row's
@@ -268,16 +351,17 @@ def _plain_bag_kernel(idx_ref, table_ref, out_ref, buf, sem, *,
     def src_fn(e):
         return jnp.maximum(entry(e), 0) // pack
 
-    def meta_fn(e):
-        return e // bag_len, entry(e) >= 0
-
-    def row_fn(e, raw):
-        shift = (width - (jnp.maximum(entry(e), 0) % pack) * (width // pack))
-        return pltpu.roll(raw.astype(jnp.float32), shift % width, 1)
+    def take_fn(e, raw):
+        ent = entry(e)                  # the entry's one SMEM read here
+        val = raw.astype(jnp.float32)
+        if pack > 1:
+            shift = width - (jnp.maximum(ent, 0) % pack) * (width // pack)
+            val = pltpu.roll(val, shift % width, 1)
+        return e // bag_len, jnp.where(ent >= 0, val, 0.0)
 
     acc = jnp.zeros((tile_b, width), jnp.float32)
-    acc = _dma_accumulate(acc, table_ref, buf, sem, 0, tile_b * bag_len,
-                          src_fn, meta_fn, row_fn if pack > 1 else None)
+    acc = _ring_accumulate(acc, table_ref, buf, sem, tile_b * bag_len,
+                           src_fn, take_fn, group)
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
@@ -527,10 +611,12 @@ def _out_struct(shape, dtype, operands) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _scratch(dim: int, dtype, n_slots: int = 2):
+def _scratch(dim: int, dtype, n_slots: int | None = 2):
     """Row-DMA scratch: ``n_slots`` (1, dim) VMEM slots + matching DMA
-    semaphores. ``_dma_accumulate`` reads the pipeline depth off the buffer
-    shape, so this is the single knob the autotuner turns."""
+    semaphores (None: the two-slot ping-pong). The accumulate loops read
+    the pipeline depth off the buffer shape, so this is the single knob the
+    autotuner turns."""
+    n_slots = 2 if n_slots is None else n_slots
     assert n_slots >= 1, n_slots
     return [pltpu.VMEM((n_slots, 1, dim), dtype),
             pltpu.SemaphoreType.DMA((n_slots,))]
@@ -540,7 +626,7 @@ def banked_embedding_bag_pallas(table: jax.Array, bank: jax.Array,
                                 slot: jax.Array, field_offsets: jax.Array,
                                 my_bank: jax.Array, idx: jax.Array, *,
                                 tile_b: int = 8, interpret: bool = False,
-                                k_max: int = 1, n_slots: int = 2
+                                k_max: int = 1, n_slots: int | None = None
                                 ) -> jax.Array:
     """One bank's stage-2 partial bag sums.
 
@@ -553,7 +639,8 @@ def banked_embedding_bag_pallas(table: jax.Array, bank: jax.Array,
     block of local rows: its SMEM holds ``tile_b * L`` entries whatever the
     vocab or the batch. ``k_max > 1`` serves a REPLICATED table: bank/slot
     are the flattened ``(V * k_max,)`` replica-axis remap and each bag reads
-    replica column ``wang_hash(bag) % k_max``.
+    replica column ``wang_hash(bag) % k_max``. ``n_slots`` as in
+    ``embedding_bag_pallas``.
     """
     rows = resolve_entries(idx, bank, slot, field_offsets, my_bank, k_max)
     return embedding_bag_pallas(table, rows, tile_b=tile_b,
@@ -598,33 +685,49 @@ def tiered_embedding_bag_pallas(payload: jax.Array, scale_bits: jax.Array,
 
 def embedding_bag_pallas(table: jax.Array, idx: jax.Array, *,
                          tile_b: int = 8, interpret: bool = False,
-                         n_slots: int = 2) -> jax.Array:
+                         n_slots: int | None = None) -> jax.Array:
     """Plain bag sum: table (V, D); idx (B, L) table rows, -1 padded ->
     (B, D).
 
     Each grid step gets its tile's ``tile_b * L`` rows as an SMEM block, and
     the table stays in HBM in the ``pack_lanes`` layout: SMEM use is bounded
     by the tile, so any vocab and any batch fit, and HBM holds the table at
-    its own size.
+    its own size. ``n_slots`` is the row-copy ring's depth; None picks
+    ``bag_ring_depth``'s.
     """
-    B, L = idx.shape
-    D = table.shape[1]
-    assert B % tile_b == 0, (B, tile_b)
     packed, pack = pack_lanes(table)
+    out = packed_bag_pallas(packed, pack, idx, tile_b=tile_b,
+                            interpret=interpret, n_slots=n_slots)
+    return out[:, :table.shape[1]]
+
+
+def packed_bag_pallas(packed: jax.Array, pack: int, idx: jax.Array, *,
+                      tile_b: int = 8, interpret: bool = False,
+                      n_slots: int | None = None) -> jax.Array:
+    """``embedding_bag_pallas`` over a table already in the ``pack_lanes``
+    layout ``(packed, pack)``: -> (B, W) lane rows, ``W = packed.shape[1]``,
+    each bag's sum in lanes [0, D)."""
+    B, L = idx.shape
+    assert B % tile_b == 0, (B, tile_b)
     W = packed.shape[1]
+    n = tile_b * L
+    depth = bag_ring_depth(n_slots, n, interpret)
+    assert depth >= 2, depth
+    # the largest divisor of BAG_GROUP that divides the tile's entries and
+    # half the depth, so the ring holds two slot groups or more
+    group = math.gcd(BAG_GROUP, depth // 2, n)
     kernel = functools.partial(_plain_bag_kernel, tile_b=tile_b, bag_len=L,
-                               pack=pack)
-    out = pl.pallas_call(
+                               pack=pack, group=group)
+    return pl.pallas_call(
         kernel, grid=(B // tile_b,),
         in_specs=[pl.BlockSpec((tile_b, L), lambda b: (b, 0),
                                memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((tile_b, W), lambda b: (b, 0)),
-        scratch_shapes=_scratch(W, table.dtype, n_slots),
-        out_shape=_out_struct((B, W), table.dtype, (idx, packed)),
+        scratch_shapes=_scratch(W, packed.dtype, depth),
+        out_shape=_out_struct((B, W), packed.dtype, (idx, packed)),
         interpret=interpret, name="updlrm_bag",
     )(idx, packed)
-    return out[:, :D]
 
 
 def plain_cache_bag_pallas(emt: jax.Array, cache: jax.Array,
@@ -695,14 +798,14 @@ def fused_cache_bag_pallas(emt: jax.Array, cache: jax.Array,
     )(*args)
 
 
-def _scatter_scratch(dim: int, ct_dtype, out_dtype, n_slots: int = 2):
+def _scatter_scratch(dim: int, ct_dtype, out_dtype,
+                     n_slots: int | None = 2):
     """Backward scratch: the cotangent INPUT stream shares the N-slot
-    ``_dma_accumulate`` pipeline, but the accumulated-row OUTPUT ping-pong in
-    ``_ct_scatter_kernel`` is hard-coded two-deep (its start/wait guards are
-    written against slot reuse at distance 2), so that pair stays (2, ...)."""
-    assert n_slots >= 1, n_slots
-    return [pltpu.VMEM((n_slots, 1, dim), ct_dtype),
-            pltpu.SemaphoreType.DMA((n_slots,)),
+    ``_dma_accumulate`` pipeline (``_scratch``), but the accumulated-row
+    OUTPUT ping-pong in ``_ct_scatter_kernel`` is hard-coded two-deep (its
+    start/wait guards are written against slot reuse at distance 2), so that
+    pair stays (2, ...)."""
+    return [*_scratch(dim, ct_dtype, n_slots),
             pltpu.VMEM((2, 1, dim), out_dtype),
             pltpu.SemaphoreType.DMA((2,))]
 
@@ -716,7 +819,7 @@ def _dest_slots(rows: jax.Array, n_rows: int) -> jax.Array:
 
 def _ct_scatter_call(ct: jax.Array, dest: jax.Array, bags: jax.Array,
                      n_rows: int, out_dtype, *, tile_s: int,
-                     interpret: bool, n_slots: int = 2) -> jax.Array:
+                     interpret: bool, n_slots: int | None = 2) -> jax.Array:
     """Shared pallas_call plumbing for the backward scatters: run the sort
     prep, then the sorted-run kernel with the d_table aliased to zeros."""
     E = dest.shape[0]
@@ -755,7 +858,8 @@ def ct_scatter_bag_pallas(ct: jax.Array, idx: jax.Array, bank: jax.Array,
                           slot: jax.Array, field_offsets: jax.Array,
                           my_bank: jax.Array, n_rows: int, out_dtype, *,
                           tile_s: int = 8, interpret: bool = False,
-                          k_max: int = 1, n_slots: int = 2) -> jax.Array:
+                          k_max: int = 1, n_slots: int | None = 2
+                          ) -> jax.Array:
     """Transpose of ``banked_embedding_bag_pallas``: scatter-add the bag
     cotangents back onto one bank's rows, entirely in the kernel layer.
 

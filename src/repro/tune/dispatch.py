@@ -93,11 +93,12 @@ def signature(path: str, *, vocab: int, dim: int, batch: int, bag_len,
 @dataclasses.dataclass(frozen=True)
 class Decision:
     """What a resolved lookup runs with. ``source`` records whether the
-    cache hit ("cache") or the deterministic default applied ("default")."""
+    cache hit ("cache") or the deterministic default applied ("default");
+    ``n_slots`` None leaves the ring depth to the kernel."""
 
     backend: str
     tile_b: int
-    n_slots: int
+    n_slots: int | None
     source: str = "default"
 
     def __post_init__(self):
@@ -251,10 +252,12 @@ def _default_backend() -> str:
 def decide(path: str, *, vocab: int, dim: int, batch: int, bag_len,
            n_fields: int = 1, k_max: int = 1, tier_mix: str = "none",
            bwd_backend: str = "auto", default_backend: str | None = None,
-           default_tile_b: int = 8, default_n_slots: int = 2) -> Decision:
+           default_tile_b: int = 8,
+           default_n_slots: int | None = 2) -> Decision:
     """Resolve one call signature: the cached decision on a hit, today's
     defaults (caller's tile_b/n_slots + the pre-tuner auto backend) on a
-    miss. Pure host-side; called at trace time by ``core/embedding``."""
+    miss. A ``default_n_slots`` of None stays None, so the kernel picks its
+    own depth. Pure host-side; called at trace time by ``core/embedding``."""
     sig = signature(path, vocab=vocab, dim=dim, batch=batch, bag_len=bag_len,
                     n_fields=n_fields, k_max=k_max, tier_mix=tier_mix,
                     bwd_backend=bwd_backend)
@@ -262,5 +265,7 @@ def decide(path: str, *, vocab: int, dim: int, batch: int, bag_len,
     if dec is not None:
         return dec
     return Decision(backend=default_backend or _default_backend(),
-                    tile_b=int(default_tile_b), n_slots=int(default_n_slots),
+                    tile_b=int(default_tile_b),
+                    n_slots=None if default_n_slots is None
+                    else int(default_n_slots),
                     source="default")

@@ -40,3 +40,31 @@ def host_trace(tmp_path):
             evs.sort(key=lambda ev: ev[0])
         return out, spans
     return record
+
+
+@pytest.fixture
+def ring_depths():
+    """``depths(fn, *args)``: the row-copy ring depth of every
+    ``updlrm_bag`` kernel that ``fn(*args)`` traces, read off the kernel's
+    VMEM ring scratch (its first scratch operand). Only traces ``fn``, so
+    it reads a compiled-mode kernel on a machine with no TPU too."""
+    def depths(fn, *args):
+        import jax
+        from jax.extend import core as jcore
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if (eqn.primitive.name == "pallas_call"
+                        and eqn.params["name"] == "updlrm_bag"):
+                    kernel = eqn.params["jaxpr"]
+                    n = eqn.params["grid_mapping"].num_scratch_operands
+                    yield kernel.invars[len(kernel.invars) - n].aval.shape[0]
+                for p in eqn.params.values():
+                    for sub in p if isinstance(p, (tuple, list)) else [p]:
+                        if isinstance(sub, jcore.ClosedJaxpr):
+                            yield from walk(sub.jaxpr)
+                        elif isinstance(sub, jcore.Jaxpr):
+                            yield from walk(sub)
+
+        return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+    return depths

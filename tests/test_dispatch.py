@@ -101,6 +101,50 @@ def test_miss_falls_back_to_callers_defaults():
     assert cache.misses == 1 and cache.hits == 0
 
 
+def test_miss_leaves_an_unset_ring_depth_to_the_kernel():
+    dec = decide("plain", vocab=50, dim=8, batch=4, bag_len=2,
+                 default_backend="pallas", default_n_slots=None)
+    assert dec == Decision(backend="pallas", tile_b=8, n_slots=None,
+                           source="default")
+
+
+@pytest.mark.parametrize("recorded,n_slots,want", [
+    (None, None, "kernel"),     # a miss, nothing passed: the kernel's ring
+    (None, 3, 3),               # a miss with the caller's depth
+    (5, None, 5),               # a cache hit's depth
+])
+def test_tuned_lookup_reaches_the_kernel_with_its_ring_depth(
+        monkeypatch, ring_depths, recorded, n_slots, want):
+    """On the chip the serve path runs ``backend='tuned'`` and misses the
+    cache; a depth nobody passed has to reach the compiled ``updlrm_bag``
+    unset, so the kernel's own ring engages. Traced as the chip would
+    trace it (pallas, compiled mode); nothing is lowered."""
+    import jax
+    import jax.numpy as jnp
+
+    import repro.core.embedding as E
+    import repro.tune.dispatch as D
+    from repro.core.partitioning import uniform_partition
+    from repro.kernels.embedding_bag import bag_ring_depth
+    monkeypatch.setattr(D, "_default_backend", lambda: "pallas")
+    monkeypatch.setattr(E, "_default_interpret", lambda interpret: False)
+    v, d, nb, L = 64, 32, 16, 8
+    cache = DispatchCache()
+    if recorded is not None:
+        cache.record(signature("plain", vocab=v, dim=d, batch=nb, bag_len=L),
+                     backend="pallas", tile_b=8, n_slots=recorded)
+    set_cache(cache)
+    bt = E.pack_table(np.zeros((v, d), np.float32), uniform_partition(v, 1))
+    kw = {} if n_slots is None else {"n_slots": n_slots}
+    depths = ring_depths(
+        lambda t, i: E.banked_embedding_bag(t, i, None, backend="tuned",
+                                            **kw),
+        bt, jax.ShapeDtypeStruct((nb, L), jnp.int32))
+    if want == "kernel":
+        want = bag_ring_depth(None, 8 * L, interpret=False)
+    assert depths == [want]
+
+
 def test_hit_returns_recorded_decision():
     cache = DispatchCache()
     sig = signature("plain", vocab=50, dim=8, batch=4, bag_len=2)

@@ -14,9 +14,12 @@ import pytest
 
 from repro.core.embedding import (BankedTable, banked_cache_residual_bag,
                                   banked_embedding_bag, csr_embedding_bag,
-                                  pack_table)
-from repro.core.partitioning import non_uniform_partition, uniform_partition
+                                  pack_replicated, pack_table,
+                                  replicated_embedding_bag)
+from repro.core.partitioning import (non_uniform_partition,
+                                     replicated_partition, uniform_partition)
 from repro.kernels import ref as REF
+from repro.kernels.embedding_bag import BAG_RING_DEPTH, bag_ring_depth
 
 
 def _banked(rng, v, d, banks, dtype=jnp.float32):
@@ -60,6 +63,75 @@ def test_multifield_pallas_matches_jnp_and_ref(d, dtype):
                                np.asarray(got_j, np.float32), atol=atol)
     np.testing.assert_allclose(np.asarray(got_p, np.float32),
                                np.asarray(want, np.float32), atol=atol)
+
+
+# ---------------------------------------------------------------------------
+# the updlrm_bag row-copy ring: every depth sums each bag in the same order
+# ---------------------------------------------------------------------------
+
+RING_L = 6                      # a tile of 8 bags holds 48 entries
+# the ping-pong, odd (groups of 1), groups of 4, the chosen depth (groups
+# of 8), and a ring deeper than a tile's entries
+RING_DEPTHS = [2, 3, 24, BAG_RING_DEPTH, 8 * RING_L + 3]
+
+
+def _ring_bags(rng, pattern, n, f, vocab):
+    """(n, f, RING_L) per-field ids: ``all_pad`` empties every third bag
+    and pads the rest at the end, ``holes`` puts -1s between valid ids."""
+    idx = rng.integers(0, vocab, (n, f, RING_L)).astype(np.int32)
+    if pattern == "all_pad":
+        lens = rng.integers(1, RING_L + 1, (n, f))
+        lens.reshape(-1)[::3] = 0
+        idx[np.arange(RING_L) >= lens[..., None]] = -1
+    else:
+        idx[rng.random(idx.shape) < 0.4] = -1
+        idx[..., -1] = rng.integers(0, vocab, (n, f))   # holes stay interior
+    return jnp.asarray(idx)
+
+
+@pytest.mark.parametrize("k_max", [1, 2])
+@pytest.mark.parametrize("pattern", ["all_pad", "holes"])
+@pytest.mark.parametrize("n_slots", RING_DEPTHS)
+def test_bag_ring_depth_bit_exact_to_scan(n_slots, pattern, k_max):
+    """The pallas forward at every ring depth and copy group in
+    ``RING_DEPTHS`` equals the jnp scan bit for bit: the ring changes when
+    rows arrive, never the j-ascending fp32 order they are added in."""
+    rng = np.random.default_rng(100 * n_slots + k_max)
+    vocab_sizes, d, banks = (40, 30, 26), 16, 4
+    v = sum(vocab_sizes)
+    fo = jnp.asarray(np.concatenate([[0], np.cumsum(vocab_sizes)[:-1]]),
+                     jnp.int32)
+    table = rng.standard_normal((v, d)).astype(np.float32)
+    freq = rng.random(v) + 0.1
+    idx = _ring_bags(rng, pattern, 7, len(vocab_sizes), min(vocab_sizes))
+    if k_max == 1:
+        bt = pack_table(table, non_uniform_partition(freq, banks))
+
+        def lookup(backend, **kw):
+            return banked_embedding_bag(bt, idx, None, backend=backend,
+                                        field_offsets=fo, **kw)
+    else:
+        copies = np.where(freq > np.quantile(freq, 0.8), k_max, 1)
+        rt = pack_replicated(table, replicated_partition(
+            freq, banks, copies=copies.astype(np.int32), k_max=k_max))
+
+        def lookup(backend, **kw):
+            return replicated_embedding_bag(rt, idx, None, backend=backend,
+                                            field_offsets=fo, **kw)
+    got = lookup("pallas", n_slots=n_slots, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(lookup("jnp")))
+
+
+@pytest.mark.parametrize("n_slots,entries,interpret,want", [
+    (None, 2048, False, BAG_RING_DEPTH),      # compiled: the deep ring
+    (None, 4, False, 4),                      # capped at a tile's entries
+    (None, 2048, True, 2),                    # interpret: the ping-pong
+    (3, 2048, False, 3),                      # an explicit depth wins
+    (99, 4, True, 99),
+])
+def test_bag_ring_depth_choice(n_slots, entries, interpret, want):
+    assert bag_ring_depth(n_slots, entries, interpret) == want
 
 
 @pytest.mark.parametrize("d", [8, 33])

@@ -54,24 +54,48 @@ def _paper():
     return get_arch("updlrm-paper").config
 
 
-@pytest.mark.parametrize("k_max", [1, 2])
-def test_banked_bag_kernel_full_vocab(one_chip, k_max):
-    """updlrm-paper's forward kernel at full width: 8 x 2,360,650 rows x 32
-    fp32, 64 requests x 8 fields = 512 bags x 256 ids. SMEM holds a tile of
-    entries, not the vocab-sized remap or the batch's ids."""
+def _full_vocab_bag(one_chip, nb: int, k_max: int = 1, **kw):
+    """updlrm-paper's forward kernel at full width over ``nb`` bags of 256
+    ids, as (jitted function, argument types)."""
     from repro.kernels.embedding_bag import banked_embedding_bag_pallas
     cfg, S = _paper(), _sds(one_chip)
     V, D, F, L = cfg.total_vocab, cfg.embed_dim, cfg.n_sparse, cfg.multi_hot
-    NB = 64 * F
     step = jax.jit(lambda t, b, s, o, m, i: banked_embedding_bag_pallas(
-        t, b, s, o, m, i, tile_b=8, k_max=k_max))
-    compiled = step.lower(
-        S((V, D), jnp.float32), S((V * k_max,), jnp.int32),
-        S((V * k_max,), jnp.int32), S((F,), jnp.int32), S((1,), jnp.int32),
-        S((NB, L), jnp.int32)).compile()
+        t, b, s, o, m, i, tile_b=8, k_max=k_max, **kw))
+    return step, (S((V, D), jnp.float32), S((V * k_max,), jnp.int32),
+                  S((V * k_max,), jnp.int32), S((F,), jnp.int32),
+                  S((1,), jnp.int32), S((nb, L), jnp.int32))
+
+
+@pytest.mark.parametrize("k_max", [1, 2])
+def test_banked_bag_kernel_full_vocab(one_chip, ring_depths, k_max):
+    """updlrm-paper's forward kernel at full width: 8 x 2,360,650 rows x 32
+    fp32, 64 requests x 8 fields = 512 bags x 256 ids, with the row-copy
+    ring at its compiled depth. SMEM holds a tile of entries, not the
+    vocab-sized remap or the batch's ids; VMEM the ring's slots."""
+    from repro.kernels.embedding_bag import BAG_RING_DEPTH
+    step, args = _full_vocab_bag(one_chip, 64 * _paper().n_sparse, k_max)
+    assert ring_depths(step, *args) == [BAG_RING_DEPTH]
+    compiled = step.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     m = compiled.memory_analysis()
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.parametrize("requests,n_slots", [(1024, None), (64, 2),
+                                              (64, 64)])
+def test_bag_kernel_ring_depths_compile(one_chip, ring_depths, requests,
+                                        n_slots):
+    """The kernel at the bulk cell's 1,024 requests and the chosen depth,
+    and at the serve cell's 64 with the two-slot ping-pong and with 64
+    slots, the deepest ring swept on the chip: every ring's VMEM slots and
+    DMA semaphores fit."""
+    from repro.kernels.embedding_bag import BAG_RING_DEPTH
+    kw = {} if n_slots is None else {"n_slots": n_slots}
+    step, args = _full_vocab_bag(one_chip, requests * _paper().n_sparse,
+                                 **kw)
+    assert ring_depths(step, *args) == [n_slots or BAG_RING_DEPTH]
+    assert "tpu_custom_call" in step.lower(*args).compile().as_text()
 
 
 @pytest.fixture(scope="module")
