@@ -89,6 +89,14 @@ def make_weights(cfg: dict, seed: int) -> dict:
     return _make(_frozen(cfg), jnp.asarray(weight_key(seed)))
 
 
+def bag_width(cfg: dict) -> int:
+    """Positions per field in the program's layout: ``multi_hot``, or the
+    longest field's where it gives one length per field (the shorter
+    fields' bags are padded with -1)."""
+    lens = cfg.get("multi_hot", 1)
+    return max(lens) if isinstance(lens, list) else int(lens)
+
+
 def program_config(cfg: dict):
     """The program's ``DLRMConfig`` for the configuration file."""
     from repro.models.dlrm import DLRMConfig
@@ -96,7 +104,7 @@ def program_config(cfg: dict):
         name=cfg["name"], vocab_sizes=tuple(cfg["vocab_sizes"]),
         embed_dim=cfg["embed_dim"], n_dense=cfg["n_dense"],
         bot_mlp=tuple(cfg["bot_mlp"]), top_mlp=tuple(cfg["top_mlp"]),
-        multi_hot=cfg.get("multi_hot", 1), dtype=jnp.dtype(cfg["dtype"]),
+        multi_hot=bag_width(cfg), dtype=jnp.dtype(cfg["dtype"]),
         emb_dtype=jnp.dtype(cfg["emb_dtype"]))
 
 
@@ -149,7 +157,7 @@ class System:
                              f"{got} != {shape0}")
         self.tables = serve_tables(statics)
         self.batch = batch
-        F, L = len(cfg["vocab_sizes"]), int(cfg.get("multi_hot", 1))
+        F, L = len(cfg["vocab_sizes"]), bag_width(cfg)
         self.batch_type = {
             "dense": jax.ShapeDtypeStruct((batch, cfg["n_dense"]),
                                           jnp.float32),

@@ -16,12 +16,23 @@ Two modes:
   ``batch`` samples, scored back to back.
 
 Each request or sample has ``dense`` (``n_dense`` float32 features, standard
-normal) and ``sparse`` ids: one id per field for one-hot configurations, or a
-bag of ``multi_hot`` positions per field, the first ``len`` of them ids and
-the rest -1, with ``len ~ Poisson(bag.mean)`` clipped to ``[bag.min,
-bag.max]``. Ids are drawn per field from a Zipf(``ids.a``) law over the
-field's vocabulary, and hot ranks are scattered over the ids by a seeded
-permutation of each field, as in the program's ``zipf_popularity``.
+normal) and ``sparse`` ids, in one of three layouts by the configuration's
+``multi_hot``:
+
+* ``1`` (or absent): one id per field, ``(n, F)``;
+* an integer ``L > 1``: a bag of ``L`` positions per field, ``(n, F, L)``,
+  the first ``len`` of them ids and the rest -1, with ``len ~
+  Poisson(bag.mean)`` clipped to ``[bag.min, bag.max]``;
+* a list of one length ``L_f`` per field, as MLPerf's multi-hot DLRM-DCNv2
+  states its ``multi_hot_sizes``: field f's bag holds exactly ``L_f`` ids,
+  laid out as ``(n, F, max L_f)`` with -1 after them (``(n, F)`` where every
+  ``L_f`` is 1). Its lengths are fixed, so a mix that gives it a ``bag`` law
+  (or a Table-1 ``profile``, which brings one) is an error.
+
+Ids are drawn per field from a Zipf(``ids.a``) law over the field's
+vocabulary (only for the positions that hold ids, in the list layout), and
+hot ranks are scattered over the ids by a seeded permutation of each field,
+as in the program's ``zipf_popularity``.
 """
 from __future__ import annotations
 
@@ -60,6 +71,7 @@ def load_mix(name: str, traffic_dir: str = TRAFFIC_DIR) -> dict:
         raise FileNotFoundError(f"no traffic mix {name!r} at {path}")
     with open(path) as f:
         mix = json.load(f)
+    mix.setdefault("name", name)
     prof = mix.get("profile")
     if prof is not None:
         t1 = TABLE1[prof]
@@ -127,13 +139,39 @@ def bag_lengths(rng: np.random.Generator, bag: dict, shape: tuple,
     return np.clip(rng.poisson(float(bag["mean"]), shape), lo, hi)
 
 
+def field_lengths(cfg: dict, mix: dict) -> list | None:
+    """The bag length of each field where the configuration's ``multi_hot``
+    is a list, else None; a list with a ``bag`` law in the mix raises."""
+    lens = cfg.get("multi_hot", 1)
+    if not isinstance(lens, list):
+        return None
+    name = cfg.get("name", "<unnamed>")
+    if len(lens) != len(cfg["vocab_sizes"]) or min(lens) < 1:
+        raise ValueError(f"configuration {name!r}: multi_hot {lens} must give "
+                         f"each of its {len(cfg['vocab_sizes'])} fields a "
+                         f"length of 1 or more")
+    if "bag" in mix:
+        raise ValueError(f"configuration {name!r} fixes each field's bag "
+                         f"length (multi_hot {lens}), but mix "
+                         f"{mix.get('name', '<unnamed>')!r} gives a bag law "
+                         f"{mix['bag']}: a mix for it states none")
+    return lens
+
+
 def samples(rng: np.random.Generator, cfg: dict, mix: dict, n: int,
             perms: list) -> dict:
     """``n`` samples of the configuration's inputs under the mix."""
     vocab = cfg["vocab_sizes"]
     a = float(mix["ids"]["a"])
-    L = int(cfg.get("multi_hot", 1))
     dense = rng.standard_normal((n, cfg["n_dense"])).astype(np.float32)
+    lens = field_lengths(cfg, mix)
+    if lens is not None:
+        sparse = np.full((n, len(vocab), max(lens)), -1, np.int32)
+        for f, (v, length, p) in enumerate(zip(vocab, lens, perms)):
+            sparse[:, f, :length] = draw_ids(rng, v, a, (n, length), p)
+        return {"dense": dense,
+                "sparse": sparse[:, :, 0] if max(lens) == 1 else sparse}
+    L = int(cfg.get("multi_hot", 1))
     if L == 1:
         sparse = np.stack([draw_ids(rng, v, a, (n,), p)
                            for v, p in zip(vocab, perms)], axis=1)

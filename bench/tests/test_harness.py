@@ -12,7 +12,8 @@ import pytest
 
 from bench import run
 from bench.families import dlrm as fam
-from conftest import ROOT, small_config, small_mix
+from bench import gen
+from conftest import DATA, ROOT, small_config, small_mix
 
 BENCH = run.load_benchmark()
 CELLS = {w["name"]: w for w in BENCH["workloads"]}
@@ -60,6 +61,51 @@ def test_an_answer_altered_where_it_is_produced_fails(cell, monkeypatch):
     assert out["correct"] is False and out["failed"] > 0
     assert out["compared"]["score_gap"]["value"] > \
         out["compared"]["score_gap"]["limit"]
+
+
+#: a committed cell of each mode, whose end-to-end metrics a per-field run
+#: of that mode reports
+CELL_OF_MODE = {"open": "paper-serve", "closed": "paper-bulk"}
+#: the CPU computes in fp32 as the reference does: the scores differ by
+#: summation order alone
+PER_FIELD_LIMITS = {"score_gap": 1e-4}
+
+
+def _run_per_field(mode):
+    """A whole run of ``data/multi-hot-small.json`` (MLPerf DLRM-DCNv2's 26
+    fields and per-field bag lengths, at a CPU's size) under the data mix of
+    ``mode``: nothing of the harness but its files."""
+    with open(os.path.join(DATA, "multi-hot-small.json")) as f:
+        cfg = json.load(f)
+    return run.run_cell(cfg, gen.load_mix(f"multi-hot-{mode}", DATA),
+                        PER_FIELD_LIMITS, seed=2**31 + 23, seconds=0.4,
+                        trace=False, t_start=time.monotonic(),
+                        require_chip=False,
+                        metrics=run.cell_metrics(BENCH, CELL_OF_MODE[mode],
+                                                 False))
+
+
+@pytest.mark.parametrize("mode", sorted(CELL_OF_MODE))
+def test_a_per_field_configuration_runs_whole_and_correct(mode):
+    out = _run_per_field(mode)
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] > 0
+    want = {m["name"] for m in run.cell_metrics(BENCH, CELL_OF_MODE[mode],
+                                                False)}
+    assert set(out["metrics"]) == want
+    assert all(v["value"] > 0 for v in out["metrics"].values())
+    assert out["compared"]["score_gap"]["value"] <= \
+        PER_FIELD_LIMITS["score_gap"]
+
+
+@pytest.mark.parametrize("mode", sorted(CELL_OF_MODE))
+def test_a_per_field_answer_altered_where_it_is_produced_fails(
+        mode, monkeypatch):
+    _tampered(monkeypatch, lambda s: s.at[0].add(0.05))
+    out = _run_per_field(mode)
+    assert out["correct"] is False and out["failed"] > 0
+    assert out["compared"]["score_gap"]["value"] > \
+        PER_FIELD_LIMITS["score_gap"]
 
 
 def test_an_answer_that_never_comes_fails(monkeypatch):

@@ -60,6 +60,55 @@ def test_reference_agrees_with_the_serve_step(arch, backend):
     assert np.abs(low - ref).max() > 10 * TOL
 
 
+#: a reduced configuration with one bag length per field
+PER_FIELD = {"name": "per-field", "family": "dlrm",
+             "vocab_sizes": [300, 5000, 7, 40], "embed_dim": 8,
+             "n_dense": 13, "bot_mlp": [16, 8], "top_mlp": [16],
+             "multi_hot": [3, 1, 12, 2], "dtype": "float32",
+             "emb_dtype": "float32", "table_scale": 0.1}
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_reference_agrees_with_the_serve_step_per_field(backend):
+    """Per-field bag lengths reach the program as ``(n, F, max L_f)`` with
+    -1 pads; the step (pallas interpreted on the CPU) and the reference
+    agree at ``TOL``, and a bf16 table is still told apart."""
+    cfg = PER_FIELD
+    mix = {"mode": "closed", "batch": 32, "ring": 1, "ids": {"a": 1.1}}
+    feats = gen.make_traffic(cfg, mix, 2**31 + 9, 1.0).ring[0]
+    assert feats["sparse"].shape == (32, 4, 12)
+    assert fam.program_config(cfg).multi_hot == 12
+    weights = fam.make_weights(cfg, 9)
+    got = _served(cfg, weights, feats, backend)
+    ref = reference.scores_in_blocks(
+        weights, feats, reference.field_offsets(cfg["vocab_sizes"]),
+        block=16)
+    assert np.abs(got - ref).max() <= TOL
+    w16 = {**weights, "table": weights["table"].astype(jnp.bfloat16)
+           .astype(weights["table"].dtype)}
+    low = _served(cfg, w16, feats, backend)
+    assert np.abs(low - ref).max() > 10 * TOL
+
+
+def test_pooled_skips_each_fields_padding():
+    """Fields of bag lengths 1, 4 and 2 in one (N, F, 4) layout: each
+    field's sum is of its own ids alone."""
+    rng = np.random.default_rng(0)
+    vocab, lens = [5, 9, 4], [1, 4, 2]
+    table = rng.standard_normal((sum(vocab), 3)).astype(np.float32)
+    off = reference.field_offsets(vocab)
+    ids = np.full((6, 3, 4), -1, np.int32)
+    for f, (v, length) in enumerate(zip(vocab, lens)):
+        ids[:, f, :length] = rng.integers(0, v, (6, length))
+    got = np.asarray(reference.pooled(jnp.asarray(table), jnp.asarray(ids),
+                                      jnp.asarray(off), jnp.float32))
+    want = np.zeros((6, 3, 3), np.float32)
+    for f, length in enumerate(lens):
+        for j in range(length):
+            want[:, f] += table[ids[:, f, j] + off[f]]
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
 def test_pooled_sums_bags_and_skips_empty_positions():
     table = jnp.arange(12, dtype=jnp.float32).reshape(6, 2)
     ids = jnp.asarray([[[0, 1, -1], [2, -1, -1]]], jnp.int32)   # (1, 2, 3)

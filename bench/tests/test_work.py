@@ -50,6 +50,32 @@ def test_work_counts_only_valid_ids():
     assert full.bytes - half.bytes == 64 * 8 * 128 * 32 * 4
 
 
+def test_per_field_bags_count_their_ids_not_the_padding():
+    """One bag length per field: ``sum(L_f)`` id positions a sample, though
+    the program's layout pads every field to the longest; rows only of the
+    valid ids."""
+    from bench import gen
+    from bench.families import dlrm as fam
+    cfg = {"vocab_sizes": [300, 5000, 7, 40], "embed_dim": 8, "n_dense": 13,
+           "bot_mlp": [16, 8], "top_mlp": [16], "multi_hot": [3, 1, 12, 2],
+           "dtype": "float32", "emb_dtype": "float32"}
+    B = 64
+    lk = work.lookup(cfg, batch_size=B, n_ids=B * 18)
+    assert lk.flops == B * 18 * 8
+    assert lk.bytes == B * 18 * 8 * 4 + B * 18 * 4 + B * 4 * 8 * 4
+    mix = {"mode": "closed", "batch": B, "ring": 1, "ids": {"a": 1.1}}
+    batch = gen.make_traffic(cfg, mix, 5, 1.0).ring[0]
+    assert batch["sparse"].shape == (B, 4, 12)      # 48 positions, 18 ids
+    assert fam.lookup_work(cfg, batch) == lk
+    assert fam.step_work(cfg, batch) == work.dlrm_step(
+        cfg, batch_size=B, n_ids=B * 18)
+    # an integer multi_hot of the longest field's length counts every
+    # position of the padded layout
+    padded = work.lookup({**cfg, "multi_hot": 12}, batch_size=B,
+                         n_ids=B * 18)
+    assert padded.bytes - lk.bytes == B * (48 - 18) * 4
+
+
 def test_unknown_device_kind_raises():
     with pytest.raises(KeyError, match="no peaks"):
         work.peaks("TPU v9 imaginary")

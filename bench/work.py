@@ -10,8 +10,11 @@ One DLRM step over ``batch_size`` samples with ``n_ids`` valid ids in all:
 * FLOPs: 2 per multiply-add of the bottom and top MLPs, 2 * D per pair
   i < j of the interaction's F + 1 vectors, and D adds per id pooled;
 * bytes: each valid id's row at its stored width, the ids (int32, every
-  position), the dense inputs (float32), the MLP weights and biases, the
-  pooled bags at the table's width, and the scores (float32).
+  position of the configuration's bags: ``F * multi_hot`` a sample, or
+  ``sum(multi_hot)`` where it gives one length per field, whatever padding
+  the program's layout carries), the dense inputs (float32), the MLP
+  weights and biases, the pooled bags at the table's width, and the scores
+  (float32).
 """
 from __future__ import annotations
 
@@ -72,7 +75,9 @@ def lookup(cfg: dict, *, batch_size: int, n_ids: int) -> Work:
     add, write the pooled bags."""
     d = cfg["embed_dim"]
     f = len(cfg["vocab_sizes"])
-    positions = batch_size * f * int(cfg.get("multi_hot", 1))
+    lens = cfg.get("multi_hot", 1)
+    positions = batch_size * (sum(lens) if isinstance(lens, list)
+                              else f * int(lens))
     emb = _itemsize(cfg["emb_dtype"])
     return Work(flops=float(n_ids * d),
                 bytes=float(n_ids * d * emb + positions * 4
